@@ -149,9 +149,8 @@ def bench_pallas_path():
         executable (the per-neuron knob costs nothing extra);
     plus the (bm, bn, bk) block-shape autotune sweep.  Emits CSV rows
     AND machine-readable BENCH_pallas_path.json (the perf trajectory
-    artifact; uploaded by CI).  On CPU the kernel runs in interpret
-    mode — the numbers are correctness-path timings, the ranking is
-    only meaningful on TPU.
+    artifact; uploaded by CI).  The kernel runs in interpret mode: the
+    numbers are CPU correctness-path timings, not chip measurements.
     """
     import json
 
@@ -160,13 +159,12 @@ def bench_pallas_path():
     from benchmarks.common import time_call
     from repro.core.quantization import quantize
     from repro.kernels.approx_mac.ops import (approx_dense_pallas,
-                                              autotune_block_shapes,
-                                              default_interpret)
+                                              autotune_block_shapes)
     from repro.nn.layers import dense
 
-    interpret = default_interpret()
-    iters = 3 if interpret else 20
-    m, k, n = (256, 256, 256) if interpret else (1024, 1024, 1024)
+    interpret = True
+    iters = 3
+    m, k, n = 256, 256, 256
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(k, n)) * 0.05, jnp.float32)
@@ -191,11 +189,10 @@ def bench_pallas_path():
     t_mixed = time_call(f_fused, x, cfg_vec, iters=iters)
     tune = autotune_block_shapes(
         m, k, n, config=8, interpret=interpret, iters=iters,
-        candidates=((128, 128, 128), (128, 128, 256), (256, 128, 256))
-        if interpret else None)
+        candidates=((128, 128, 128), (128, 128, 256), (256, 128, 256)))
     best = tune[0] if tune and "us" in tune[0] else None
 
-    mode = "interpret" if interpret else "tpu"
+    mode = "interpret"
     print(f"pallas_path_xla_{m}x{k}x{n},{t_xla:.1f},mode={mode}")
     print(f"pallas_path_fused_{m}x{k}x{n},{t_fused:.1f},"
           f"xla_vs_pallas={t_xla/t_fused:.2f}x")
@@ -234,23 +231,22 @@ def bench_moe_path():
         same grouped executable (the expert knob costs nothing extra);
       * expert-count scaling: both paths at E = 2 / 4 / 8;
     Emits CSV rows AND machine-readable BENCH_moe_pallas.json (uploaded
-    by CI).  On CPU the kernels run in interpret mode — the numbers are
-    correctness-path timings; TPU is the performance target.
+    by CI).  The kernels run in interpret mode: the numbers are CPU
+    correctness-path timings, not chip measurements.
     """
     import json
 
     import jax
     import jax.numpy as jnp
     from benchmarks.common import time_call
-    from repro.kernels.approx_mac.ops import default_interpret
     from repro.nn.moe import moe_ffn
 
-    interpret = default_interpret()
-    iters = 3 if interpret else 20
-    t, d, f, k = (64, 64, 128, 2) if interpret else (4096, 1024, 4096, 2)
+    interpret = True
+    iters = 3
+    t, d, f, k = 64, 64, 128, 2
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(t, d)), jnp.float32)
-    mode = "interpret" if interpret else "tpu"
+    mode = "interpret"
     scaling = []
     for e in (2, 4, 8):
         params = {
@@ -483,6 +479,9 @@ def bench_sharded_decode():
     import sys
 
     env = dict(os.environ)
+    # the child runs on forced host CPU devices, never on a chip: a
+    # parent that has touched JAX already holds it
+    env["JAX_PLATFORMS"] = "cpu"
     # preserve inherited platform flags, but OUR device count must win
     # (a conflicting inherited force-device flag would be ambiguous)
     flags = [f for f in env.get("XLA_FLAGS", "").split()
@@ -668,15 +667,21 @@ JSON_BENCHES = ["pallas_path", "moe_path", "scheduler", "resilience",
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     which = sys.argv[1:] or list(BENCHES)
     if which == ["all"]:
         which = JSON_BENCHES
     print("name,us_per_call,derived")
+    failed = []
     for name in which:
         try:
             BENCHES[name]()
-        except Exception as e:  # keep the harness running
+        except Exception as e:  # keep the harness running, fail at exit
             print(f"{name},ERROR,{type(e).__name__}:{e}")
+            failed.append(name)
+    if failed:
+        sys.exit(f"benches raised: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -14,11 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def pipeline_forward(stage_fn, stage_weights, microbatches, mesh,
                      stage_axis: str = "stage"):
@@ -50,9 +45,9 @@ def pipeline_forward(stage_fn, stage_weights, microbatches, mesh,
         final = jax.lax.psum(final, stage_axis)
         return final[n_stages - 1:n_stages - 1 + m]
 
-    return _shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(stage_axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(stage_weights, microbatches)

@@ -13,11 +13,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:                                       # jax >= 0.6 moved shard_map
-    from jax import shard_map as _shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 NEG_INF = -1e30
 
 
@@ -59,9 +54,9 @@ def sp_decode_attention(q, k_cache, v_cache, cache_len, mesh,
         return (o_glob / denom.transpose(0, 2, 1)[..., None]).astype(q.dtype)
 
     spec_kv = P(None, seq_axis, None, None)
-    return _shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), spec_kv, spec_kv),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(q, k_cache, v_cache)

@@ -81,14 +81,22 @@ class Mapping:
         return axes if len(axes) > 1 else axes[0]
 
     def spec(self, logical: Sequence, shape: Sequence[int]) -> P:
-        """PartitionSpec for one array from its logical axes + shape."""
+        """PartitionSpec for one array from its logical axes + shape.
+
+        Trailing replicated dims are left out: that is the form jit
+        gives its outputs, so an array placed here and the same array
+        coming back out of a jitted step carry equal shardings and hit
+        one executable (a decode loop feeding its own cache)."""
         if len(logical) != len(shape):
             # spec/shape rank mismatch (e.g. scalar with a stale spec):
             # replicate rather than guess.
             return P()
         used: set[str] = set()
-        return P(*[self._resolve_one(n, d, used)
-                   for n, d in zip(logical, shape)])
+        axes = [self._resolve_one(n, d, used)
+                for n, d in zip(logical, shape)]
+        while axes and axes[-1] is None:
+            axes.pop()
+        return P(*axes)
 
     def named(self, logical: Sequence, shape: Sequence[int]) -> NamedSharding:
         return NamedSharding(self.mesh, self.spec(logical, shape))
@@ -107,8 +115,7 @@ class Mapping:
                 return self.replicated()
             first = (self.batch_axes if len(self.batch_axes) > 1
                      else self.batch_axes[0])
-            return NamedSharding(
-                self.mesh, P(first, *([None] * (len(shape) - 1))))
+            return NamedSharding(self.mesh, P(first))
         return jax.tree.map(one, tree)
 
     def shardings(self, spec_tree, shape_tree):

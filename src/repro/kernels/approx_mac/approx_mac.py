@@ -55,17 +55,19 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.approx_matmul import operand_param_table
 from repro.core.approx_multiplier import OPERAND_PARAM_TABLE
 from repro.core.quantization import QMAX, truncate_operand_lsb
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 
 def _truncate(v, depth, gate, rtn):
-    """Elementwise int8->int32 magnitude truncation (VPU ops only).
+    """Elementwise int8 -> int8 magnitude truncation (VPU ops only).
 
     depth/gate/rtn are traced int32 scalars read from SMEM, so this is
     exactly the traced branch of core.quantization.truncate_operand_lsb
     — ONE definition of the bit-level semantics shared by the XLA path
-    and the kernel (pure jnp integer ops, pallas-traceable)."""
-    return truncate_operand_lsb(v, depth, gate, rtn).astype(jnp.int32)
+    and the kernel (pure jnp integer ops, pallas-traceable).  Truncated
+    magnitudes stay within QMAX, so the result is still int8 and the
+    MXU contracts int8 x int8 -> int32 exactly (Mosaic refuses int32
+    matmul operands)."""
+    return truncate_operand_lsb(v, depth, gate, rtn)
 
 
 def _block_cfg(cfg_ref):
@@ -150,18 +152,12 @@ def config_operand(config, n_blocks: int = 1) -> jax.Array:
 
 def _grid_call(kernel, n_prefetch, grid, in_specs, out_shape, scratch,
                interpret):
-    """pallas_call through PrefetchScalarGridSpec when available, else
-    plain SMEM inputs (same kernel signature; loses only the prefetch
-    hint).  in_specs are the non-scalar specs with index maps taking one
-    argument per grid dimension (the contraction dim is last/innermost)
-    — prefetch args are appended automatically."""
+    """pallas_call through PrefetchScalarGridSpec: the first
+    `n_prefetch` operands land in SMEM.  in_specs are the non-scalar
+    specs with index maps taking one argument per grid dimension (the
+    contraction dim is last/innermost) — the prefetch refs the index
+    maps also receive are dropped here."""
     ng = len(grid)
-    common = dict(
-        out_shape=out_shape,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel",) * (ng - 1) + ("arbitrary",)),
-        interpret=interpret,
-    )
     bspecs, ospec = in_specs
 
     def with_prefetch(spec):
@@ -170,24 +166,18 @@ def _grid_call(kernel, n_prefetch, grid, in_specs, out_shape, scratch,
             spec.block_shape,
             lambda *a, _m=index_map: _m(*a[:ng]))
 
-    if hasattr(pltpu, "PrefetchScalarGridSpec"):
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=n_prefetch,
-            grid=grid,
-            in_specs=[with_prefetch(s) for s in bspecs],
-            out_specs=with_prefetch(ospec),
-            scratch_shapes=scratch,
-        )
-        return pl.pallas_call(kernel, grid_spec=grid_spec, **common)
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=n_prefetch,
         grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] * n_prefetch
-        + list(bspecs),
-        out_specs=ospec,
+        in_specs=[with_prefetch(s) for s in bspecs],
+        out_specs=with_prefetch(ospec),
         scratch_shapes=scratch,
-        **common,
     )
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * (ng - 1) + ("arbitrary",)),
+        interpret=interpret)
 
 
 def approx_mac_matmul(a, b, config=0, *, bm: int = 128,
@@ -295,7 +285,7 @@ def _grouped_kernel(cfg_ref, rows_ref, xscale_ref, x_ref, b_ref, scale_ref,
 
     @pl.when(pl.program_id(3) == k_steps - 1)
     def _done():
-        o_ref[0] = acc_ref[...].astype(jnp.float32) * scale_ref[...]
+        o_ref[0] = acc_ref[...].astype(jnp.float32) * scale_ref[0]
 
 
 def grouped_config_operand(config, n_experts: int,
@@ -354,7 +344,9 @@ def approx_mac_grouped_matmul(x, w_q, scale_rows, x_scale, group_rows,
         ([
             pl.BlockSpec((1, bm, bk), lambda g, i, j, ks: (g, i, ks)),
             pl.BlockSpec((1, bk, bn), lambda g, i, j, ks: (g, ks, j)),
-            pl.BlockSpec((1, bn), lambda g, i, j, ks: (g, j)),
+            # (E, 1, N): a (1, bn) block of an (E, N) array would tile
+            # the expert axis by 1, which Mosaic refuses
+            pl.BlockSpec((1, 1, bn), lambda g, i, j, ks: (g, 0, j)),
         ], pl.BlockSpec((1, bm, bn), lambda g, i, j, ks: (g, i, j))),
         jax.ShapeDtypeStruct((e, m, n), jnp.float32),
         [pltpu.VMEM((bm, bn), jnp.int32)],
@@ -363,4 +355,4 @@ def approx_mac_grouped_matmul(x, w_q, scale_rows, x_scale, group_rows,
     return call(grouped_config_operand(config, e, n // bn),
                 jnp.asarray(group_rows, jnp.int32).reshape(e),
                 jnp.asarray(x_scale, jnp.float32).reshape(1),
-                x.astype(jnp.float32), w_q, scale_rows)
+                x.astype(jnp.float32), w_q, scale_rows.reshape(e, 1, n))

@@ -36,11 +36,6 @@ from .approx_mac import (approx_mac_fused_matmul, approx_mac_grouped_matmul,
                          approx_mac_matmul)
 
 
-def default_interpret() -> bool:
-    """True when the Pallas kernels must run in interpret mode (no TPU)."""
-    return jax.default_backend() != "tpu"
-
-
 _MRED_RANK_DEV: list = []
 _ERROR_RANK_DEV: list = []
 
@@ -313,7 +308,7 @@ DEFAULT_BLOCK_CANDIDATES = (
 
 def autotune_block_shapes(m: int, k: int, n: int, *, config=8,
                           candidates=None, fused: bool = True,
-                          interpret: bool | None = None,
+                          interpret: bool = False,
                           iters: int = 5, seed: int = 0):
     """Measure the fused approx-dense over (bm, bn, bk) candidates for a
     GEMM shape; returns a list of {"bm","bn","bk","us"} dicts sorted
@@ -324,7 +319,6 @@ def autotune_block_shapes(m: int, k: int, n: int, *, config=8,
     machinery and feeds BENCH_pallas_path.json.
     """
     import numpy as np
-    interpret = default_interpret() if interpret is None else interpret
     candidates = list(candidates or DEFAULT_BLOCK_CANDIDATES)
     rng = np.random.default_rng(seed)
     x = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)

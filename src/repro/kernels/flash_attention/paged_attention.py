@@ -9,17 +9,15 @@ index maps dereference ``bt_ref`` to pick the physical block for each
 owns and never materialises the gathered (B, P*bs, KV, hd) view the XLA
 path builds.
 
-Grid: (batch*heads, pages) with the page dimension innermost
-("arbitrary") so the online-softmax m/l/acc carries live across pages.
-Pages past ``ceil(len/bs)`` still iterate but are fully masked —
-block-skipping via a per-row page count is the same documented perf
-follow-up as flash_attention's masked KV blocks.  GQA indexes the KV
-head as q_head // group in the index maps, like flash_attention.
-
-Environments whose pallas build lacks ``PrefetchScalarGridSpec`` (the
-index maps *need* the table ref, so approx_mac's plain-SMEM fallback
-cannot express the gather) fall back to the XLA reference — numerically
-identical masking, one gathered dot instead of a page stream.
+Grid: (batch, pages) with the page dimension innermost ("arbitrary") so
+the online-softmax m/l/acc carries live across pages.  Each grid step
+covers every head of its row: q is blocked (1, H, hd) and each K/V page
+(1, bs, KV*hd), so every block's last two dims are whole array dims —
+the TPU tiling rule — whatever the head counts.  GQA runs one
+(group, hd) x (hd, bs) dot per KV head, the group's query heads
+sharing that head's page.  Pages past ``ceil(len/bs)`` still iterate
+but are fully masked — block-skipping via a per-row page count is the
+same documented perf follow-up as flash_attention's masked KV blocks.
 """
 from __future__ import annotations
 
@@ -30,17 +28,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
 from repro.nn.attention import decode_attention
 
 NEG_INF = -1.0e30
 
 
 def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-            m_ref, l_ref, acc_ref, *, scale, logit_cap, bs, pages, h):
-    bh = pl.program_id(0)
+            m_ref, l_ref, acc_ref, *, scale, logit_cap, bs, pages, kv, hd):
+    b = pl.program_id(0)
     pi = pl.program_id(1)
-    b = bh // h
+    group = q_ref.shape[1] // kv
 
     @pl.when(pi == 0)
     def _init():
@@ -48,40 +45,36 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)                  # (1, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)         # (bs, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if logit_cap > 0.0:
-        s = jnp.tanh(s / logit_cap) * logit_cap
     key_pos = pi * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
     mask = key_pos < len_ref[b]
-    s = jnp.where(mask, s, NEG_INF)
+    for g in range(kv):
+        rows = slice(g * group, (g + 1) * group)
+        q = q_ref[0, rows, :].astype(jnp.float32)              # (group, hd)
+        k = k_ref[0, :, g * hd:(g + 1) * hd].astype(jnp.float32)  # (bs, hd)
+        v = v_ref[0, :, g * hd:(g + 1) * hd].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if logit_cap > 0.0:
+            s = jnp.tanh(s / logit_cap) * logit_cap
+        s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[...]                               # (1, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    # fully-masked page: s == m_new == NEG_INF would give exp(0) = 1 —
-    # force masked probabilities to exactly zero.
-    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+        m_prev = m_ref[rows, :]                                # (group, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        # fully-masked page: s == m_new == NEG_INF would give exp(0) = 1
+        # — force masked probabilities to exactly zero.
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[rows, :] = (l_ref[rows, :] * alpha
+                          + jnp.sum(p, axis=-1, keepdims=True))
+        acc_ref[rows, :] = acc_ref[rows, :] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[rows, :] = m_new
 
     @pl.when(pi == pages - 1)
     def _done():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-
-def _pad_hd(x, mult: int = 128):
-    pad = (-x.shape[-1]) % mult
-    if pad == 0:
-        return x
-    width = [(0, 0)] * (x.ndim - 1) + [(0, pad)]
-    return jnp.pad(x, width)
 
 
 def paged_attention_reference(q, k_pool, v_pool, tables, cache_len, *,
@@ -105,52 +98,36 @@ def paged_decode_attention(q, k_pool, v_pool, tables, cache_len, *,
                            scale: float | None = None,
                            interpret: bool = False):
     """Same contract as ``paged_attention_reference``, via the kernel."""
-    if not hasattr(pltpu, "PrefetchScalarGridSpec"):
-        return paged_attention_reference(q, k_pool, v_pool, tables,
-                                         cache_len, logit_cap=logit_cap,
-                                         scale=scale)
     b, sq, h, hd = q.shape
     assert sq == 1, "paged decode kernel is single-token"
-    kv = k_pool.shape[2]
+    nb, bs, kv, _ = k_pool.shape
     assert h % kv == 0
-    group = h // kv
-    bs = k_pool.shape[1]
     pages = tables.shape[1]
     scale = hd ** -0.5 if scale is None else scale
-    qp = _pad_hd(q[:, 0])                                 # (B, H, hd')
-    kp = _pad_hd(k_pool)
-    vp = _pad_hd(v_pool)
-    hdp = qp.shape[-1]
     kernel = functools.partial(_kernel, scale=scale, logit_cap=logit_cap,
-                               bs=bs, pages=pages, h=h)
+                               bs=bs, pages=pages, kv=kv, hd=hd)
+    page = pl.BlockSpec((1, bs, kv * hd), lambda bi, pi, bt, sl:
+                        (bt[bi, pi], 0, 0))
+    row = pl.BlockSpec((1, h, hd), lambda bi, pi, bt, sl: (bi, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b * h, pages),
-        in_specs=[
-            pl.BlockSpec((1, 1, hdp),
-                         lambda bh, pi, bt, sl: (bh // h, bh % h, 0)),
-            pl.BlockSpec((1, bs, 1, hdp),
-                         lambda bh, pi, bt, sl:
-                         (bt[bh // h, pi], 0, (bh % h) // group, 0)),
-            pl.BlockSpec((1, bs, 1, hdp),
-                         lambda bh, pi, bt, sl:
-                         (bt[bh // h, pi], 0, (bh % h) // group, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, hdp),
-                               lambda bh, pi, bt, sl: (bh // h, bh % h, 0)),
+        grid=(b, pages),
+        in_specs=[row, page, page],
+        out_specs=row,
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, hdp), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, hd), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, hdp), q.dtype),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((b, h, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(tables, jnp.int32), jnp.asarray(cache_len, jnp.int32),
-      qp, kp, vp)
-    return out[..., :hd][:, None]                         # (B, 1, H, hd)
+      q[:, 0], k_pool.reshape(nb, bs, kv * hd),
+      v_pool.reshape(nb, bs, kv * hd))
+    return out[:, None]                                   # (B, 1, H, hd)

@@ -38,17 +38,11 @@ def make_serve_mesh(*, dp: int = 1, tp: int | None = None):
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary mesh (e.g. (4,2) on 8 forced host devices).
-
-    Handles the jax API drift around explicit axis types: on versions
-    that have ``jax.sharding.AxisType`` every axis is created Auto; older
-    versions (<= 0.4.x) only know Auto meshes, so the kwarg is omitted.
-    """
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh (e.g. (4,2) on 8 forced host devices), every axis
+    Auto: the model's logical-axis constraints leave partitioning to
+    GSPMD."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 # TPU v5e hardware constants used by the roofline analysis

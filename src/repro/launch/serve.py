@@ -68,12 +68,14 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import sys
 import time
 
 import jax
 import numpy as np
 
 from repro.configs.registry import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.nn import transformer as T
 from repro.serve.engine import Engine, Request
 
@@ -150,6 +152,7 @@ def main():
                     help="draft depth per speculative tick (the "
                          "scheduler may lower it live)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:
@@ -165,13 +168,23 @@ def main():
         mapping = serve_mapping(make_serve_mesh(dp=dp, tp=tp), kv=args.kv)
         print(f"mesh ({dp}, {tp}) over {dp * tp} devices, kv={args.kv}")
 
-    params, specs = T.init_lm(jax.random.PRNGKey(0), cfg)
+    key = jax.random.PRNGKey(0)
     if args.ckpt:
         from repro.checkpoint.checkpointer import Checkpointer
+        box = {}
+
+        def float_params(key):
+            params, box["specs"] = T.init_lm(key, cfg)
+            return params
+
         ck = Checkpointer(args.ckpt)
-        state, _ = ck.restore({"params": params})
-        params = state["params"]
+        state, _ = ck.restore({"params": jax.eval_shape(float_params, key)})
+        params, specs = T.quantize_lm_params(state["params"], cfg), box["specs"]
         print(f"restored checkpoint step {ck.latest_step()}")
+    else:
+        # random weights, quantized layer by layer: the float model is
+        # never resident (DESIGN.md §3)
+        params, specs = T.init_serving_lm(key, cfg)
 
     sched = None
     if args.budget_frac is not None:
@@ -346,6 +359,11 @@ def main():
         print(f"traffic: {tot['offered']} offered, availability "
               f"{tot['availability']*100:.1f}%, SLO attainment "
               f"{tot['slo_attainment']*100:.1f}%")
+    failed = sum(r.status == "failed" for r in done)
+    if failed:
+        print(f"{failed} requests failed; last error: {eng.last_error}",
+              file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

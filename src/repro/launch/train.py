@@ -24,6 +24,7 @@ from repro.configs.registry import get_config
 from repro.data.synthetic_lm import SyntheticLM, SyntheticLMConfig
 from repro.dist.fault_tolerance import resilient_train_loop
 from repro.dist.sharding import Mapping, activate, train_state_specs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.nn import transformer as T
 from repro.train.optimizer import adamw
 from repro.train.schedule import warmup_cosine
@@ -45,6 +46,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="experiments/ckpt_train")
     ap.add_argument("--ckpt-every", type=int, default=100)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -218,6 +218,25 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window: int = 0,
     if window > 0:
         valid &= pos >= limit - window
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
-    w = jax.nn.softmax(scores, axis=-1)
+    e = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
+    w = e / _tree_sum(e)
     out = jnp.einsum("bhqk,bkhd->bqhd", w, v_r.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+def _tree_sum(x):
+    """Sum over the last axis, keepdims, in an order the code fixes:
+    halves are added elementwise until one column is left.
+
+    A reduction's order is the compiler's choice, and on a TPU it follows
+    the whole program's shapes: a softmax denominator XLA computes as a
+    full-row reduce-window on one chip becomes a plain reduce on each
+    shard of a (2, 2) mesh, and the two round differently.  Elementwise
+    adds are never reassociated, so this sum gives the same bits for a
+    row whatever batch or heads share its program (DESIGN.md §8)."""
+    while x.shape[-1] > 1:
+        if x.shape[-1] % 2:
+            x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, 1)])
+        half = x.shape[-1] // 2
+        x = x[..., :half] + x[..., half:]
+    return x

@@ -124,7 +124,7 @@ class QuantizedMLP:
         return config, config
 
     def apply(self, x_q, config=0, method: str = "lut",
-              interpret: bool | None = None):
+              interpret: bool = False):
         """Integer forward pass under error config `config` (jax arrays).
 
         x_q: (B, 62) int8.  Returns (B, 10) int32 logits (accumulator
@@ -132,13 +132,11 @@ class QuantizedMLP:
         hardware's maximum-value circuit).  method: "lut" (bit-exact
         ASIC oracle), "operand" (TPU-native XLA adaptation), or
         "pallas" (the approx-MAC kernel — same operand semantics, run
-        through the fused serving kernel; `interpret` defaults to auto:
-        interpret mode off-TPU)."""
+        through the fused serving kernel; `interpret=True` runs it in
+        Pallas interpret mode, as on CPU)."""
         if method == "pallas":
-            from repro.kernels.approx_mac.ops import (approx_mac,
-                                                      default_interpret)
-            itp = default_interpret() if interpret is None else interpret
-            mm = lambda a, b, c: approx_mac(a, b, c, interpret=itp)
+            from repro.kernels.approx_mac.ops import approx_mac
+            mm = lambda a, b, c: approx_mac(a, b, c, interpret=interpret)
         else:
             mm = (approx_matmul_lut if method == "lut"
                   else approx_matmul_operand)

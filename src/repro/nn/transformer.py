@@ -277,18 +277,52 @@ def _block_init(rng, cfg, kind: str):
     return p, s
 
 
-def _stack(trees):
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
-
-
 def _stack_specs(spec, n):
     """Prepend the scan ("layers") axis to every spec tuple."""
     return jax.tree.map(lambda t: (None,) + tuple(t), spec,
                         is_leaf=lambda t: isinstance(t, tuple))
 
 
+def _stack_map(f, xs):
+    """``lax.map``'s contract as an eager Python loop + stack."""
+    return jax.tree.map(lambda *ys: jnp.stack(ys), *[f(x) for x in xs])
+
+
+def _init_stack(rngs, cfg, pattern, convert, map_fn):
+    """Blocks tree (+ specs) for len(rngs) layers cycling `pattern`:
+    whole pattern periods stacked under "scan" (``map_fn`` over the
+    periods' keys), the remainder as "rest{r}".  `convert` maps each
+    block's params as they are made."""
+    npat = len(pattern)
+    n_groups, rem = len(rngs) // npat, len(rngs) % npat
+    bp, bs = {}, {}
+    if n_groups:
+        gspec = {}
+
+        def group(keys):
+            gp = {}
+            for j, kind in enumerate(pattern):
+                p, gspec[f"b{j}"] = _block_init(keys[j], cfg, kind)
+                gp[f"b{j}"] = convert(p)
+            return gp
+
+        keys = rngs[:n_groups * npat]
+        bp["scan"] = map_fn(
+            group, keys.reshape((n_groups, npat) + keys.shape[1:]))
+        bs["scan"] = _stack_specs(gspec, n_groups)
+    for r in range(rem):
+        p, bs[f"rest{r}"] = _block_init(rngs[n_groups * npat + r], cfg,
+                                        pattern[r % npat])
+        bp[f"rest{r}"] = convert(p)
+    return bp, bs
+
+
 def init_lm(rng, cfg: ModelConfig):
     """Returns (params, logical_specs)."""
+    return _init_lm(rng, cfg, lambda p: p, _stack_map)
+
+
+def _init_lm(rng, cfg, convert, map_fn):
     ks = jax.random.split(rng, 8)
     params: Params = {}
     specs: Params = {}
@@ -297,52 +331,18 @@ def init_lm(rng, cfg: ModelConfig):
     if not cfg.tie_embeddings:
         params["lm_head"] = dense_init(ks[1], cfg.d_model, cfg.vocab_size)
         specs["lm_head"] = ("fsdp", "vocab")
-
-    def make_blocks(rng, n_layers, pattern, dec=False):
-        kinds = [pattern[i % len(pattern)] for i in range(n_layers)]
-        npat = len(pattern)
-        n_groups, rem = n_layers // npat, n_layers % npat
-        rngs = jax.random.split(rng, n_layers)
-        bp, bs = {}, {}
-        if n_groups:
-            groups = []
-            gspec = None
-            for g in range(n_groups):
-                gp = {}
-                for j in range(npat):
-                    li = g * npat + j
-                    p, sp = _block_init(rngs[li], cfg, kinds[li])
-                    gp[f"b{j}"] = p
-                    if g == 0:
-                        gspec = gspec or {}
-                        gspec[f"b{j}"] = sp
-                groups.append(gp)
-            bp["scan"] = _stack(groups)
-            bs["scan"] = _stack_specs(gspec, n_groups)
-        for r in range(rem):
-            li = n_groups * npat + r
-            p, sp = _block_init(rngs[li], cfg, kinds[li])
-            bp[f"rest{r}"] = p
-            bs[f"rest{r}"] = sp
-        return bp, bs
-
-    params["blocks"], specs["blocks"] = make_blocks(ks[2], cfg.n_layers,
-                                                    cfg.pattern)
+    params["blocks"], specs["blocks"] = _init_stack(
+        jax.random.split(ks[2], cfg.n_layers), cfg, cfg.pattern, convert,
+        map_fn)
     fn, fs = _norm_init(cfg)
     params["final_norm"], specs["final_norm"] = fn, fs
 
     if cfg.encoder_decoder:
         # encoder: non-causal global attention blocks (no cross-attn)
         enc_cfg = dataclasses.replace(cfg, encoder_decoder=False)
-        ep, es = {}, {}
-        kinds = ["global"] * cfg.n_enc_layers
-        rngs = jax.random.split(ks[3], cfg.n_enc_layers)
-        groups = [dict(b0=_block_init(rngs[g], enc_cfg, "global")[0])
-                  for g in range(cfg.n_enc_layers)]
-        gspec = {"b0": _block_init(rngs[0], enc_cfg, "global")[1]}
-        ep["scan"] = _stack(groups)
-        es["scan"] = _stack_specs(gspec, cfg.n_enc_layers)
-        params["encoder"], specs["encoder"] = ep, es
+        params["encoder"], specs["encoder"] = _init_stack(
+            jax.random.split(ks[3], cfg.n_enc_layers), enc_cfg,
+            ("global",), convert, map_fn)
         en, esn = _norm_init(cfg)
         params["enc_norm"], specs["enc_norm"] = en, esn
         params["enc_pos"] = (jax.random.normal(ks[4], (cfg.max_positions,
@@ -473,6 +473,35 @@ def quantize_lm_specs(specs, cfg: ModelConfig):
     return _map_quantized_nodes(specs, conv_attn, conv_mlp)
 
 
+def _quantize_attn(d):
+    out = dict(d)
+    for key in ("wq", "wk", "wv"):
+        if key in d and not isinstance(d[key], QTensor):
+            a = d[key]
+            lead = a.ndim - 3
+            a2 = a.reshape(a.shape[:lead + 1] + (-1,))
+            out[key] = _vmapped_quantize(a2, 2)
+    if "wo" in d and not isinstance(d["wo"], QTensor):
+        a = d["wo"]
+        lead = a.ndim - 3
+        a2 = a.reshape(a.shape[:lead] + (-1, a.shape[-1]))
+        out["wo"] = _vmapped_quantize(a2, 2)
+    return out
+
+
+def _quantize_mlp(d):
+    if not d:
+        return d
+    out = dict(d)
+    for key in _QUANT_MLP_KEYS:
+        if key in d and not isinstance(d[key], QTensor):
+            # expert tensors (E, in, out) vmap into stacked banks with
+            # (E, out) scales; dense mats quantize in place — same code
+            # path, the expert axis is just one more leading dim
+            out[key] = _vmapped_quantize(d[key], 2)
+    return out
+
+
 def quantize_lm_params(params, cfg: ModelConfig):
     """Pre-quantize every GEMM weight that flows through ``dense`` into
     a QTensor ONCE — the serving engine calls this at init so no decode
@@ -489,37 +518,39 @@ def quantize_lm_params(params, cfg: ModelConfig):
     ``moe.quantize_expert_bank`` applied per trace, so pre-quantizing
     kills the per-call expert requantize without changing a bit.  The
     router and recurrent cells keep per-call quantization.  Returns a
-    new params tree; embed/lm_head/norms stay float.
+    new params tree; embed/lm_head/norms stay float.  Weights that are
+    already QTensors (``init_serving_lm``) pass through, so the call is
+    idempotent.
     """
-    def conv_attn(d):
-        out = dict(d)
-        for key in ("wq", "wk", "wv"):
-            if key in d:
-                a = d[key]
-                lead = a.ndim - 3
-                a2 = a.reshape(a.shape[:lead + 1] + (-1,))
-                out[key] = _vmapped_quantize(a2, 2)
-        if "wo" in d:
-            a = d["wo"]
-            lead = a.ndim - 3
-            a2 = a.reshape(a.shape[:lead] + (-1, a.shape[-1]))
-            out["wo"] = _vmapped_quantize(a2, 2)
-        return out
+    return _map_quantized_nodes(params, _quantize_attn, _quantize_mlp)
 
-    def conv_mlp(d):
-        if not d:
-            return d
-        out = dict(d)
-        for key in _QUANT_MLP_KEYS:
-            if key in d:
-                # expert tensors (E, in, out) vmap into stacked banks
-                # with (E, out) scales; dense mats quantize in place —
-                # same code path, the expert axis is just one more
-                # leading dim
-                out[key] = _vmapped_quantize(d[key], 2)
-        return out
 
-    return _map_quantized_nodes(params, conv_attn, conv_mlp)
+def init_serving_lm(rng, cfg: ModelConfig):
+    """Serving params for random weights, without the float model.
+
+    ``quantize_lm_params(init_lm(rng, cfg)[0], cfg)`` and ``init_lm``'s
+    specs, as ONE jitted program that makes a layer group's float
+    weights, quantizes them and keeps only the QTensors (a ``lax.map``
+    over the groups), so the whole float tree (12 GB for a 3B model,
+    more than a 16 GB chip holds beside its int8 copy) is never
+    resident.  Compiled, the per-channel scales may differ from the
+    eager ``quantize_lm_params`` in the last bit (XLA turns the
+    constant division into a reciprocal multiply).  The specs keep the
+    float layout, which ``Engine`` transforms itself
+    (``quantize_lm_specs``)."""
+    box = {}
+
+    def quantize_block(p):
+        return _map_quantized_nodes({"blocks": p}, _quantize_attn,
+                                    _quantize_mlp)["blocks"]
+
+    def build(rng):
+        params, box["specs"] = _init_lm(rng, cfg, quantize_block,
+                                        jax.lax.map)
+        return params
+
+    params = jax.jit(build)(rng)
+    return params, box["specs"]
 
 
 # ---------------------------------------------------------------------------

@@ -157,6 +157,17 @@ from .speculative import SpecConfig, longest_agreeing_prefix
 _ENERGY_PJ = ENERGY_PER_MAC_PJ
 
 
+def _serving_jit(fn):
+    """``jax.jit`` for the engine's model executables, compiled without
+    XLA's excess precision.  With it, XLA may skip a bf16 rounding the
+    model asks for wherever it fuses the producer into the consumer, so
+    the numerics follow fusion decisions, which differ between the MAC
+    backends (a Pallas call ends a fusion) and between executables.  On
+    a TPU v5e that alone made the xla and pallas backends' greedy tokens
+    part ways at the first token; without it they are bit-identical."""
+    return jax.jit(fn, compiler_options={"xla_allow_excess_precision": False})
+
+
 class _SpecAbort(RuntimeError):
     """Internal: roll back a speculative tick (draft-side corruption —
     the DRAFT config misbehaving must not quarantine the pool config,
@@ -553,29 +564,36 @@ class Engine:
         if paged is not None:
             backend_ = paged.attn_backend
 
-            @jax.jit
+            @_serving_jit
             def _decode(params, cache, token, acfg):
                 return T.paged_decode_step(params, cfg_, cache, token,
                                            approx_cfg=acfg,
                                            backend=backend_)
 
             self._decode = _decode
+
             # two prefill executables, ever: the one-chunk fast path
             # (stock T.prefill on a chunk-length buffer — bit-identical
             # K/V to the dense engine's padded prefill; scattered into
             # the pool on the host) and the mid-prompt chunk step
             # (slot/start/count as traced scalars)
-            self._prefill = jax.jit(
-                lambda params, tokens, acfg, true_len: T.prefill(
-                    params, cfg_, tokens, max_len=paged.prefill_chunk,
-                    approx_cfg=acfg, true_len=true_len))
-            self._prefill_chunk = jax.jit(
-                lambda params, cache, tokens, slot, start, count, acfg:
-                T.paged_prefill_chunk(params, cfg_, cache, tokens,
-                                      slot=slot, start=start, count=count,
-                                      approx_cfg=acfg))
+            @_serving_jit
+            def _prefill(params, tokens, acfg, true_len):
+                return T.prefill(params, cfg_, tokens,
+                                 max_len=paged.prefill_chunk,
+                                 approx_cfg=acfg, true_len=true_len)
+
+            @_serving_jit
+            def _prefill_chunk(params, cache, tokens, slot, start, count,
+                               acfg):
+                return T.paged_prefill_chunk(
+                    params, cfg_, cache, tokens, slot=slot, start=start,
+                    count=count, approx_cfg=acfg)
+
+            self._prefill = _prefill
+            self._prefill_chunk = _prefill_chunk
         else:
-            @jax.jit
+            @_serving_jit
             def _decode(params, cache, token, acfg):
                 cache = lsc_tree(cache, cache_spec_)
                 logits, new_cache = T.decode_step(params, cfg_, cache,
@@ -588,15 +606,16 @@ class Engine:
                 # arrive padded to the boundary, the real length rides
                 # along as a traced scalar (satellite: kills the
                 # per-prompt-length retrace)
-                self._prefill = jax.jit(
-                    lambda params, tokens, acfg, true_len: T.prefill(
-                        params, cfg_, tokens, max_len=max_len,
-                        approx_cfg=acfg, true_len=true_len))
+                @_serving_jit
+                def _prefill(params, tokens, acfg, true_len):
+                    return T.prefill(params, cfg_, tokens, max_len=max_len,
+                                     approx_cfg=acfg, true_len=true_len)
             else:
-                self._prefill = jax.jit(
-                    lambda params, tokens, acfg: T.prefill(
-                        params, cfg_, tokens, max_len=max_len,
-                        approx_cfg=acfg))
+                @_serving_jit
+                def _prefill(params, tokens, acfg):
+                    return T.prefill(params, cfg_, tokens, max_len=max_len,
+                                     approx_cfg=acfg)
+            self._prefill = _prefill
 
         # -- speculative decoding (PR 9, DESIGN.md §12) ----------------
         self.spec = spec
@@ -620,7 +639,7 @@ class Engine:
                 # shape speculation adds — k and draft_cfg are host
                 # loop count / traced data (zero retraces across the
                 # whole (k, draft-cfg) sweep)
-                self._verify = jax.jit(
+                self._verify = _serving_jit(
                     lambda params, cache, tokens, pos, acfg:
                     T.decode_verify(params, cfg_, cache, tokens, pos,
                                     approx_cfg=acfg))
@@ -1149,15 +1168,16 @@ class Engine:
             self.slots[slot] = req
 
     def _register_prefix_blocks(self, slot: int, toks: np.ndarray) -> None:
-        """Publish the slot's FULL prompt blocks for prefix reuse, keyed
-        by the token prefix they hold.  Full blocks are never written
-        again (decode appends past them), so sharing them is safe
-        without a copy; shared keys that already exist are no-ops."""
-        bs = self.paged.block_size
+        """Publish the slot's shareable prompt blocks (whole prefill
+        chunks, ``PageAllocator.shareable_blocks``) for prefix reuse,
+        keyed by the token history they depend on.  Full blocks are
+        never written again (decode appends past them), so sharing them
+        is safe without a copy; shared keys that already exist are
+        no-ops."""
         blocks = self._slot_blocks[slot]
-        for i in range(toks.size // bs):
-            key = tuple(int(t) for t in toks[: (i + 1) * bs])
-            self.allocator.register_prefix(key, blocks[i])
+        for i in range(self.allocator.shareable_blocks(toks.size)):
+            self.allocator.register_prefix(
+                self.allocator.block_key(toks, i), blocks[i])
 
     def _advance_prefills(self) -> None:
         """Advance every mid-prefill slot by ONE chunk this tick —

@@ -169,6 +169,29 @@ class PageAllocator:
             return None
         return self._prefix_index.get(key)
 
+    def shareable_blocks(self, n_tokens: int) -> int:
+        """How many leading blocks of an ``n_tokens`` token history can
+        be shared: whole prefill chunks only, one token short of the end.
+
+        Dynamic activation quantization scales a prefill chunk as a
+        whole, so a block's K/V depend on every token of the chunk that
+        computed it, not only on the tokens up to the block's end.  A
+        block is reusable only where a request that prefilled the same
+        history itself would have computed the same chunk: a full chunk
+        that is not the last (the last prompt token always prefills
+        locally — its logits seed the first sample, and a prompt that
+        fits one chunk takes a different prefill path)."""
+        c = self.cfg.prefill_chunk
+        return (max(int(n_tokens) - 1, 0) // c) * (c // self.cfg.block_size)
+
+    def block_key(self, tokens: Sequence[int], i: int) -> tuple:
+        """Prefix-index key of block ``i`` of a token history: the block
+        index followed by every token up to the end of the block's
+        prefill chunk (see ``shareable_blocks``)."""
+        c = self.cfg.prefill_chunk
+        end = (i * self.cfg.block_size // c + 1) * c
+        return (int(i),) + tuple(int(t) for t in tokens[:end])
+
     def register_prefix(self, key: tuple, blk: int) -> None:
         """Publish a fully-written prompt block for reuse."""
         if not self.cfg.share_prefixes or key in self._prefix_index:
@@ -178,24 +201,23 @@ class PageAllocator:
         self._block_keys.setdefault(blk, []).append(key)
 
     def match_prefix(self, prompt: Sequence[int]) -> list[int]:
-        """Longest run of already-cached full prompt blocks.
-
-        Sharing is capped one token short of the prompt so the last
-        prompt token is always prefilled locally — its logits seed the
-        request's first sampled token.  Matched blocks are NOT
-        incref'd; callers fork() the returned list into their table.
+        """Longest run of already-cached blocks, in whole prefill chunks
+        (``shareable_blocks``), so the rest of the prompt prefills from a
+        chunk boundary exactly as it would without sharing.  Matched
+        blocks are NOT incref'd; callers fork() the returned list into
+        their table.
         """
         if not self.cfg.share_prefixes:
             return []
-        bs = self.cfg.block_size
         toks = [int(t) for t in prompt]
         matched: list[int] = []
-        for i in range((len(toks) - 1) // bs):
-            blk = self._prefix_index.get(tuple(toks[: (i + 1) * bs]))
+        for i in range(self.shareable_blocks(len(toks))):
+            blk = self._prefix_index.get(self.block_key(toks, i))
             if blk is None:
                 break
             matched.append(blk)
-        return matched
+        per_chunk = self.cfg.prefill_chunk // self.cfg.block_size
+        return matched[: len(matched) - len(matched) % per_chunk]
 
     # -------------------------------------------------------- snapshot
     def state_dict(self) -> dict:

@@ -14,23 +14,8 @@ quantization bound, and the residual carries the difference forward.
 """
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
-
-try:                                       # jax >= 0.6: top-level shard_map
-    from jax import shard_map as _shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the replication-check kwarg was renamed check_rep -> check_vma; pick by
-# the resolved function's signature, not by import location
-import inspect as _inspect
-_SHMAP_PARAMS = _inspect.signature(_shard_map).parameters
-_SHMAP_KW = ({"check_vma": False} if "check_vma" in _SHMAP_PARAMS
-             else {"check_rep": False} if "check_rep" in _SHMAP_PARAMS
-             else {})
 
 
 def _q8(x, scale):
@@ -62,9 +47,9 @@ def compressed_psum_mean(grads, residual, mesh, axis: str = "data"):
             return mean, new_r
 
         spec = P(*([None] * g.ndim))
-        return _shard_map(body, mesh=mesh,
-                          in_specs=(spec, spec), out_specs=(spec, spec),
-                          **_SHMAP_KW)(g, r)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(spec, spec), out_specs=(spec, spec),
+                             check_vma=False)(g, r)
 
     flat_g, treedef = jax.tree.flatten(grads)
     flat_r = jax.tree.leaves(residual)

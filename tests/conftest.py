@@ -35,6 +35,7 @@ def run_forced_devices(code: str, n_devices: int = 8, timeout=560):
     env = dict(os.environ)
     env["XLA_FLAGS"] = \
         f"--xla_force_host_platform_device_count={n_devices}"
+    env["JAX_PLATFORMS"] = "cpu"       # forced host devices, never a chip
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     # subprocesses inherit the suite's strict-broadcast sanitizer
     env["JAX_NUMPY_RANK_PROMOTION"] = "raise"
@@ -48,3 +49,35 @@ def run_forced_devices(code: str, n_devices: int = 8, timeout=560):
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture(scope="session")
+def trained_demo_lm():
+    """(params, cfg, data) of a 2-layer demo LM briefly trained on the
+    synthetic corpus ``data``.
+
+    A random-init model has near-uniform logits, so every greedy argmax
+    is a near-tie.  The int8 datapath's per-tensor dynamic activation
+    scale spans the whole batch, so what else is in the batch, and how
+    wide it is, moves the last grid bit of every row; on random weights
+    that flips ties, and a token-stream bar would test luck, not the
+    contract.  Training restores the margins those bars rely on."""
+    import jax.numpy as jnp
+    from repro.data.synthetic_lm import SyntheticLM, SyntheticLMConfig
+    from repro.nn import transformer as T
+    from repro.train import optimizer as opt_mod
+    from repro.train.step import build_train_step, init_state
+    cfg = T.ModelConfig(name="demo", n_layers=2, d_model=32, n_heads=2,
+                        n_kv_heads=2, head_dim=16, d_ff=64, vocab_size=64,
+                        scan_layers=False, remat=False, q_chunk=8,
+                        loss_chunks=1, compute_dtype=jnp.float32)
+    params, _ = T.init_lm(jax.random.PRNGKey(0), cfg)
+    data = SyntheticLM(SyntheticLMConfig(vocab_size=64, seq_len=48,
+                                         global_batch=16, n_templates=4,
+                                         seed=0))
+    train = jax.jit(build_train_step(cfg, opt_mod.adamw(lr=4e-3)))
+    state = init_state(params, opt_mod.adamw(lr=4e-3))
+    for i in range(300):
+        b = data.batch(i)
+        state, _ = train(state, {k: jnp.asarray(v) for k, v in b.items()})
+    return jax.tree.map(np.asarray, state["params"]), cfg, data

@@ -22,8 +22,6 @@ mixed-class traffic) drops the bit-identity claim — the budget is
 SUPPOSED to move configs — and pins the accounting/retrace invariants
 at full load instead.
 """
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -37,31 +35,12 @@ from repro.serve.traffic import TrafficClass, TrafficGenerator
 
 
 @pytest.fixture(scope="module")
-def model():
+def model(trained_demo_lm):
     """Briefly-trained demo LM: a random-init model has near-uniform
     logits, so verify-vs-decode last-bit numerics flip argmax ties and
     the bit-identity bar would test luck, not the contract (same
     reasoning as tests/test_speculative.py)."""
-    from repro.data.synthetic_lm import SyntheticLM, SyntheticLMConfig
-    from repro.nn import transformer as T
-    from repro.train import optimizer as opt_mod
-    from repro.train.step import build_train_step, init_state
-    cfg = T.ModelConfig(name="demo", n_layers=2, d_model=32, n_heads=2,
-                        n_kv_heads=2, head_dim=16, d_ff=64,
-                        vocab_size=64, scan_layers=False, remat=False,
-                        q_chunk=8, loss_chunks=1,
-                        compute_dtype=jnp.float32)
-    params, _ = T.init_lm(jax.random.PRNGKey(0), cfg)
-    data = SyntheticLM(SyntheticLMConfig(vocab_size=64, seq_len=48,
-                                         global_batch=16, n_templates=4,
-                                         seed=0))
-    train = jax.jit(build_train_step(cfg, opt_mod.adamw(lr=4e-3)))
-    state = init_state(params, opt_mod.adamw(lr=4e-3))
-    for i in range(300):
-        b = data.batch(i)
-        state, _ = train(state,
-                         {k: jnp.asarray(v) for k, v in b.items()})
-    return jax.tree.map(np.asarray, state["params"]), cfg
+    return trained_demo_lm[:2]
 
 
 class FakeClock:

@@ -14,6 +14,8 @@ correctness rests on:
 * allocator state round-trips through ``checkpoint.Checkpointer``
   snapshot/restore bit-exactly, prefix index included.
 """
+import tempfile
+
 import numpy as np
 import pytest
 from hypothesis import given, settings
@@ -54,9 +56,8 @@ def _random_trace(alloc: PageAllocator, rng: np.random.Generator,
             tables.append(blocks)
             prompt = new_prompt(2 * alloc.cfg.block_size)
             prompts.append(prompt)
-            bs = alloc.cfg.block_size
             for i, blk in enumerate(blocks):
-                alloc.register_prefix(tuple(prompt[:(i + 1) * bs]), blk)
+                alloc.register_prefix(alloc.block_key(prompt, i), blk)
         elif op == 1 and tables:                      # release a table
             i = int(rng.integers(len(tables)))
             alloc.release(tables.pop(i))
@@ -141,45 +142,65 @@ def test_unshared_block_writes_in_place():
 
 def test_match_prefix_stops_one_token_short():
     """The last prompt token is always prefilled locally (its logits
-    seed the first sample), so an exact-multiple prompt shares one
-    block less than its full length."""
-    cfg = _cfg(block_size=4)
+    seed the first sample), and blocks are shared in whole prefill
+    chunks, so an exact-multiple prompt shares one chunk less than its
+    full length."""
+    cfg = _cfg(block_size=4)                  # prefill chunk = 8 tokens
     alloc = PageAllocator(cfg)
-    prompt = list(range(8))
-    blocks = alloc.alloc_n(2)
+    prompt = list(range(16))
+    blocks = alloc.alloc_n(4)
     for i, blk in enumerate(blocks):
-        alloc.register_prefix(tuple(prompt[:(i + 1) * 4]), blk)
-    assert alloc.match_prefix(prompt) == blocks[:1]
+        alloc.register_prefix(alloc.block_key(prompt, i), blk)
+    assert alloc.match_prefix(prompt) == blocks[:2]
     assert alloc.match_prefix(prompt + [99]) == blocks
     assert alloc.match_prefix([7, 6, 5, 4, 3]) == []
+
+
+def test_block_key_covers_its_whole_prefill_chunk():
+    """A block's K/V depend on every token of the chunk that computed it
+    (the chunk shares one activation scale), so a history that agrees
+    on the block's own tokens but not on the rest of its chunk must not
+    reuse it."""
+    alloc = PageAllocator(_cfg(block_size=4))
+    prompt = list(range(9))
+    blocks = alloc.alloc_n(2)
+    for i, blk in enumerate(blocks):
+        alloc.register_prefix(alloc.block_key(prompt, i), blk)
+    assert alloc.match_prefix(prompt) == blocks
+    assert alloc.match_prefix(prompt[:4] + [99] * 5) == []
 
 
 def test_dying_block_leaves_the_prefix_index():
     cfg = _cfg(block_size=4)
     alloc = PageAllocator(cfg)
-    prompt = list(range(8))
-    blk = alloc.alloc()
-    alloc.register_prefix(tuple(prompt[:4]), blk)
-    assert alloc.match_prefix(prompt) == [blk]
-    alloc.decref(blk)
+    prompt = list(range(9))
+    blocks = alloc.alloc_n(2)
+    for i, blk in enumerate(blocks):
+        alloc.register_prefix(alloc.block_key(prompt, i), blk)
+    assert alloc.match_prefix(prompt) == blocks
+    alloc.decref(blocks[0])
     assert alloc.match_prefix(prompt) == []
     # the id can be recycled for an unrelated request without ghosts
-    assert alloc.alloc() == blk
+    assert alloc.alloc() == blocks[0]
     assert alloc.match_prefix(prompt) == []
 
 
 @settings(max_examples=20, deadline=None)
 @given(seed=st.integers(min_value=0, max_value=10_000))
-def test_state_roundtrips_through_checkpointer(seed, tmp_path):
+def test_state_roundtrips_through_checkpointer(seed):
     rng = np.random.default_rng(seed)
     alloc = PageAllocator(_cfg())
     tables = _random_trace(alloc, rng, n_ops=25)
     state = alloc.state_dict()
 
-    ckpt = Checkpointer(str(tmp_path / f"ck{seed}"))
-    ckpt.save(0, {"refcounts": state["refcounts"]},
-              metadata={"prefix_index": state["prefix_index"]})
-    tree, meta = ckpt.restore({"refcounts": np.zeros_like(state["refcounts"])})
+    # a directory per example: hypothesis runs the body many times per
+    # test call, so a function-scoped tmp_path fixture would be shared
+    with tempfile.TemporaryDirectory() as tmp:
+        ckpt = Checkpointer(tmp)
+        ckpt.save(0, {"refcounts": state["refcounts"]},
+                  metadata={"prefix_index": state["prefix_index"]})
+        tree, meta = ckpt.restore(
+            {"refcounts": np.zeros_like(state["refcounts"])})
 
     fresh = PageAllocator(_cfg())
     fresh.load_state_dict({"refcounts": tree["refcounts"],

@@ -59,6 +59,9 @@ def serve(mapping):
         eng.submit(Request(rid=i, prompt=p, max_new_tokens=8))
     eng.run()
     warm = (eng._decode._cache_size(), eng._prefill._cache_size())
+    # the cache placed at init and the cache decode returns carry equal
+    # shardings: one decode executable from the first tick on
+    assert warm[0] == 1, warm
     # live mixed per-layer retune between batches, as a controller would
     eng.apply_allocation({0: 31, 2: 5})
     for i, p in enumerate(prompts):

@@ -54,24 +54,9 @@ def _demo_cfg():
 
 
 @pytest.fixture(scope="module")
-def model():
+def model(trained_demo_lm):
     """Briefly-trained demo LM (see module docstring for why trained)."""
-    from repro.data.synthetic_lm import SyntheticLM, SyntheticLMConfig
-    from repro.nn import transformer as T
-    from repro.train import optimizer as opt_mod
-    from repro.train.step import build_train_step, init_state
-    cfg = _demo_cfg()
-    params, _ = T.init_lm(jax.random.PRNGKey(0), cfg)
-    data = SyntheticLM(SyntheticLMConfig(vocab_size=64, seq_len=48,
-                                         global_batch=16, n_templates=4,
-                                         seed=0))
-    train = jax.jit(build_train_step(cfg, opt_mod.adamw(lr=4e-3)))
-    state = init_state(params, opt_mod.adamw(lr=4e-3))
-    for i in range(300):
-        b = data.batch(i)
-        state, _ = train(state,
-                         {k: jnp.asarray(v) for k, v in b.items()})
-    return jax.tree.map(np.asarray, state["params"]), cfg
+    return trained_demo_lm[:2]
 
 
 def _reqs(seed, n=4, plen=16, new=12, base=0, **kw):
