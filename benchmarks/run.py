@@ -162,8 +162,6 @@ def bench_pallas_path():
                                               autotune_block_shapes)
     from repro.nn.layers import dense
 
-    interpret = True
-    iters = 3
     m, k, n = 256, 256, 256
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
@@ -175,25 +173,24 @@ def bench_pallas_path():
                                        compute_dtype=jnp.float32))
     f_fused = jax.jit(lambda x, c: dense(x, w_qt, approx_cfg=c,
                                          backend="pallas",
-                                         interpret=interpret,
+                                         interpret=True,
                                          compute_dtype=jnp.float32))
     f_unfused = jax.jit(lambda x, c: approx_dense_pallas(
-        x, w_qt, config=c, fused=False, interpret=interpret,
+        x, w_qt, config=c, fused=False, interpret=True,
         compute_dtype=jnp.float32))
-    t_xla = time_call(f_xla, x, cfg, iters=iters)
-    t_fused = time_call(f_fused, x, cfg, iters=iters)
-    t_unfused = time_call(f_unfused, x, cfg, iters=iters)
+    t_xla = time_call(f_xla, x, cfg, iters=3)
+    t_fused = time_call(f_fused, x, cfg, iters=3)
+    t_unfused = time_call(f_unfused, x, cfg, iters=3)
     # per-neuron knob: a mixed per-N-block config vector, same executable
     cfg_vec = jnp.asarray([(31 * i) // max(n // 128 - 1, 1)
                            for i in range(n // 128)], jnp.int32)
-    t_mixed = time_call(f_fused, x, cfg_vec, iters=iters)
+    t_mixed = time_call(f_fused, x, cfg_vec, iters=3)
     tune = autotune_block_shapes(
-        m, k, n, config=8, interpret=interpret, iters=iters,
+        m, k, n, config=8, interpret=True, iters=3,
         candidates=((128, 128, 128), (128, 128, 256), (256, 128, 256)))
     best = tune[0] if tune and "us" in tune[0] else None
 
-    mode = "interpret"
-    print(f"pallas_path_xla_{m}x{k}x{n},{t_xla:.1f},mode={mode}")
+    print(f"pallas_path_xla_{m}x{k}x{n},{t_xla:.1f},mode=interpret")
     print(f"pallas_path_fused_{m}x{k}x{n},{t_fused:.1f},"
           f"xla_vs_pallas={t_xla/t_fused:.2f}x")
     print(f"pallas_path_unfused_{m}x{k}x{n},{t_unfused:.1f},"
@@ -206,7 +203,7 @@ def bench_pallas_path():
 
     out = {
         "bench": "pallas_path",
-        "mode": mode,
+        "mode": "interpret",
         "shape": {"m": m, "k": k, "n": n},
         "config": 8,
         "xla_vs_pallas": {"xla_us": t_xla, "pallas_fused_us": t_fused,
@@ -241,12 +238,9 @@ def bench_moe_path():
     from benchmarks.common import time_call
     from repro.nn.moe import moe_ffn
 
-    interpret = True
-    iters = 3
     t, d, f, k = 64, 64, 128, 2
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(t, d)), jnp.float32)
-    mode = "interpret"
     scaling = []
     for e in (2, 4, 8):
         params = {
@@ -264,8 +258,8 @@ def bench_moe_path():
             fn = jax.jit(lambda xx, cc: moe_ffn(
                 xx, params, n_experts=e, top_k=k, capacity_factor=1.25,
                 n_groups=1, approx_cfg=cc, backend="pallas",
-                interpret=interpret, grouped=grouped)[0])
-            return time_call(fn, x, cfg, iters=iters)
+                interpret=True, grouped=grouped)[0])
+            return time_call(fn, x, cfg, iters=3)
 
         cfg8 = jnp.asarray(8, jnp.int32)
         t_map = run(False, cfg8)
@@ -278,7 +272,7 @@ def bench_moe_path():
                         "grouped_us": t_grp, "speedup": t_map / t_grp,
                         "mixed_per_expert_us": t_mix,
                         "per_expert_overhead": t_mix / t_grp})
-        print(f"moe_path_laxmap_e{e},{t_map:.1f},mode={mode}")
+        print(f"moe_path_laxmap_e{e},{t_map:.1f},mode=interpret")
         print(f"moe_path_grouped_e{e},{t_grp:.1f},"
               f"laxmap_vs_grouped={t_map / t_grp:.2f}x")
         print(f"moe_path_mixed_per_expert_e{e},{t_mix:.1f},"
@@ -286,7 +280,7 @@ def bench_moe_path():
 
     out = {
         "bench": "moe_path",
-        "mode": mode,
+        "mode": "interpret",
         "shape": {"tokens": t, "d_model": d, "d_ff": f, "top_k": k},
         "config": 8,
         "expert_scaling": scaling,

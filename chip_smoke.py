@@ -11,8 +11,11 @@ to end, on random weights made from ``--seed``, and checks what comes out:
 * the same requests through an Engine on the fused Pallas approx-MAC
   kernel: greedy tokens identical to the XLA backend's at both configs;
 * the same requests through a paged Engine (block pool, chunked prefill,
-  the Pallas paged-attention kernel), and that kernel against its XLA
-  reference at Qwen shapes.
+  the Pallas paged-attention kernel) for health, and that kernel against
+  its XLA reference at Qwen shapes;
+* 8 equal-length requests per config admitted in lockstep through a
+  dense and a paged Engine of equal geometry: greedy tokens identical
+  (paged decode equals dense at equal occupancy, DESIGN.md §11).
 
 ``--four-chips`` runs instead the sharded path: the same requests on a
 (2, 2) ("data", "model") mesh with the KV cache sharded over heads, and on
@@ -58,6 +61,7 @@ PREFILL_PAD = 64          # one prefill executable for every prompt length
 # the README's --paged geometry (launch/serve.py, max_len 128)
 PAGED = dict(max_batch=64, max_len=128, num_blocks=258, block_size=16,
              prefill_chunk=32)
+LOCKSTEP_LEN = 32          # one prefill chunk: the paged fast path
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
@@ -88,15 +92,15 @@ class CompileLog:
               f"executables")
 
 
-def make_batches(vocab: int, seed: int):
+def make_batches(vocab: int, seed: int, length: int | None = None):
     """[(config, [(rid, prompt), ...]), ...]: N_REQUESTS seeded prompts
-    per config."""
+    per config, of `length` tokens each or of lengths in PROMPT_LEN."""
     rng = np.random.default_rng(seed)
     batches, rid = [], 0
     for c in CONFIGS:
         reqs = []
         for _ in range(N_REQUESTS):
-            n = int(rng.integers(PROMPT_LEN[0], PROMPT_LEN[1] + 1))
+            n = length or int(rng.integers(PROMPT_LEN[0], PROMPT_LEN[1] + 1))
             reqs.append((rid, rng.integers(0, vocab, size=n)))
             rid += 1
         batches.append((c, reqs))
@@ -202,13 +206,30 @@ def one_chip(cfg, params, seed: int, log: CompileLog) -> None:
                              attn_backend="pallas")
     eng = Engine(params, cfg, max_batch=PAGED["max_batch"],
                  max_len=PAGED["max_len"], paged=paged, seed=seed)
-    toks = serve(eng, batches, "paged engine")
+    serve(eng, batches, "paged engine (mixed prompt lengths)")
     del eng
-    same = sum(toks[rid] == xla[rid] for rid in toks)
-    print(f"paged engine: {same}/{len(toks)} requests token-identical to "
-          f"the dense engine (mixed-length batches may part ways, "
-          f"DESIGN.md §11)")
     paged_kernel_check(cfg, seed)
+
+    log.phase = "lockstep"
+    lockstep_check(cfg, params, seed)
+
+
+def lockstep_check(cfg, params, seed: int) -> None:
+    """Equal-length requests admitted together through a dense and a
+    paged Engine of equal batch and length: token-identical."""
+    lockstep = make_batches(cfg.vocab_size, seed + 1, length=LOCKSTEP_LEN)
+    geometry = dict(max_batch=N_REQUESTS, max_len=PAGED["max_len"])
+    eng = Engine(params, cfg, prefill_pad=PAGED["prefill_chunk"],
+                 seed=seed, **geometry)
+    dense = serve(eng, lockstep, "dense engine (lockstep)")
+    del eng
+    paged = PagedCacheConfig(
+        num_blocks=2 + N_REQUESTS * PAGED["max_len"] // PAGED["block_size"],
+        block_size=PAGED["block_size"], prefill_chunk=PAGED["prefill_chunk"])
+    eng = Engine(params, cfg, paged=paged, seed=seed, **geometry)
+    toks = serve(eng, lockstep, "paged engine (lockstep)")
+    del eng
+    compare(dense, toks, lockstep, "dense vs paged, lockstep")
 
 
 def four_chips(cfg, params, specs, seed: int, log: CompileLog) -> None:
