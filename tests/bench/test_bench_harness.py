@@ -1,0 +1,92 @@
+"""The benchmark is driven by data, and refuses to run without its chip.
+
+A configuration, a traffic mix, a cell and a metric added under new names
+in a copy of the benchmark are found by name and run end to end (at the
+models' smoke() widths, on the CPU, past the harness's look for a chip)
+with no edit to any file that was there."""
+import argparse
+import filecmp
+import json
+import os
+import shutil
+import subprocess
+import sys
+from pathlib import Path
+
+import pytest
+
+REPO = Path(__file__).resolve().parents[2]
+sys.path.insert(0, str(REPO))
+sys.path.insert(0, str(Path(__file__).resolve().parent))
+
+import _bench_smoke  # noqa: E402
+from bench import run as bench_run  # noqa: E402
+
+
+def _unchanged(copy: Path) -> bool:
+    """Every file of the repo's benchmark is byte-identical in the copy."""
+    for path in (REPO / "bench").rglob("*"):
+        if path.is_file() and "__pycache__" not in path.parts:
+            twin = copy / path.relative_to(REPO)
+            if not filecmp.cmp(path, twin, shallow=False):
+                return False
+    return True
+
+
+@pytest.mark.parametrize("cell", ["smoke-qwen.chat", "smoke-olmoe.decode"])
+def test_cells_added_as_files_are_found_and_run(tmp_path, cell):
+    root = _bench_smoke.build(tmp_path)
+    assert _unchanged(root)
+    args = argparse.Namespace(workload=cell, seed=2 ** 33 + 5, seconds=2.0,
+                              trace=0)
+    result = bench_run.run(args, root=root, require_tpu=False)
+    metrics = result["metrics"]
+    wanted = {"setup_s", "smoke.done_requests"} | (
+        {"itl_p95_ms"} if cell.endswith("chat")
+        else {"output_tok_s"})
+    assert set(metrics) == wanted
+    assert metrics["smoke.done_requests"]["value"] >= 1
+    assert result["attempted"] >= 1 and result["failed"] == 0
+    assert result["notes"]["compiles_in_window"] == 0
+    assert list(result)[-1] == "checks"
+    assert result["correct"] is True
+
+
+def test_same_seed_sends_the_same_requests_and_seeds_share_sizes():
+    from bench import generator
+    mix = json.loads((REPO / "bench" / "traffic" / "chat.json").read_text())
+    a = generator.open_loop(mix, 7, 30.0, 1000)
+    b = generator.open_loop(mix, 7, 30.0, 1000)
+    c = generator.open_loop(mix, 2 ** 40 + 3, 30.0, 1000)
+    assert [(s.due_s, s.max_new, s.prompt.tolist()) for s in a] == \
+        [(s.due_s, s.max_new, s.prompt.tolist()) for s in b]
+    assert sorted(len(s.prompt) for s in a) == \
+        sorted(len(s.prompt) for s in c)
+    assert sorted(s.max_new for s in a) == sorted(s.max_new for s in c)
+    assert [s.max_new for s in a] != [s.max_new for s in c]
+    assert all(0 <= s.due_s < 30.0 for s in a + c)
+
+
+def _run_py(cwd: Path):
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    env["JAX_PLATFORMS"] = "cpu"
+    return subprocess.run(
+        [sys.executable, "bench/run.py", "--workload", "qwen2.5-3b.chat",
+         "--seed", "3", "--seconds", "1", "--trace", "0"],
+        cwd=cwd, env=env, capture_output=True, text=True, timeout=300)
+
+
+def test_run_exits_nonzero_without_a_tpu():
+    r = _run_py(REPO)
+    assert r.returncode != 0
+    assert r.stdout.strip() == ""
+    assert "needs a TPU" in r.stderr
+
+
+def test_run_exits_nonzero_with_only_the_benchmark_files(tmp_path):
+    shutil.copytree(REPO / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(REPO / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
+    r = _run_py(tmp_path)
+    assert r.returncode != 0
+    assert r.stdout.strip() == ""
