@@ -43,6 +43,7 @@ def embed_init(rng, vocab: int, d: int, dtype=jnp.float32):
 # dense with the error-config knob
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("gemm")
 def dense(x, w, *, approx_cfg: int = 0, quantized: bool = False,
           compute_dtype=jnp.bfloat16, backend: str = "xla",
           interpret: bool = False,

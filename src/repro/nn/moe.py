@@ -59,6 +59,7 @@ def router_probs(x, w_router, *, dtype=jnp.float32):
     return jax.nn.softmax(logits, axis=-1)
 
 
+@jax.named_scope("moe")
 def moe_ffn(x, params, *, n_experts: int, top_k: int,
             capacity_factor: float = 1.25, n_groups: int = 1,
             act: str = "silu", renormalize: bool = True,
@@ -186,6 +187,7 @@ def _moe_groups(xg, params, *, n_experts: int, top_k: int,
     # default is the grouped kernel — every expert's GEMM in one
     # pallas_call; grouped=False is the per-expert lax.map A/B path,
     # bit-identical by construction.
+    @jax.named_scope("gemm")
     def expert_einsum(h, w, spec):
         from repro.core.quantization import QTensor
         vector_cfg = isinstance(approx_cfg, jax.Array) and approx_cfg.ndim >= 1

@@ -639,10 +639,11 @@ def _attention_block(p, x, cfg, kind, *, positions, approx_cfg=0,
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
     window = cfg.window if kind == "local" else 0
-    attn = chunked_attention(q, k, v, causal=causal, window=window,
-                             logit_cap=cfg.attn_softcap,
-                             scale=cfg.query_scale, q_chunk=cfg.q_chunk,
-                             unroll=cfg.unroll_chunks)
+    with jax.named_scope("attention"):
+        attn = chunked_attention(q, k, v, causal=causal, window=window,
+                                 logit_cap=cfg.attn_softcap,
+                                 scale=cfg.query_scale, q_chunk=cfg.q_chunk,
+                                 unroll=cfg.unroll_chunks)
     y = _attn_out(attn, p["attn"]["wo"], approx_cfg, cfg)
     if cfg.post_norm:
         y = _apply_norm(p["post1"], y, cfg)
@@ -656,9 +657,10 @@ def _attention_block(p, x, cfg, kind, *, positions, approx_cfg=0,
                   heads=cfg.n_kv_heads)
         v = _proj(enc_out, p["xattn"]["wv"], approx_cfg, cfg=cfg,
                   heads=cfg.n_kv_heads)
-        attn = chunked_attention(q, k, v, causal=False,
-                                 q_chunk=cfg.q_chunk,
-                                 unroll=cfg.unroll_chunks)
+        with jax.named_scope("attention"):
+            attn = chunked_attention(q, k, v, causal=False,
+                                     q_chunk=cfg.q_chunk,
+                                     unroll=cfg.unroll_chunks)
         x = res + _attn_out(attn, p["xattn"]["wo"], approx_cfg, cfg)
     res = x
     h = _apply_norm(p["norm2"], x, cfg)
@@ -839,6 +841,7 @@ def forward(params, cfg: ModelConfig, tokens, *, vision_embeds=None,
     return _apply_norm(params["final_norm"], x, cfg)
 
 
+@jax.named_scope("lm_head")
 def logits_for(params, cfg, hidden):
     w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = jnp.dot(hidden, w.astype(hidden.dtype))
@@ -1374,16 +1377,18 @@ def prefill(params, cfg: ModelConfig, tokens, *, vision_embeds=None,
                             < jnp.reshape(true_len, (1, 1)))
                 k_w = k_w * pad_mask[:, :, None, None].astype(k_w.dtype)
                 v_w = v_w * pad_mask[:, :, None, None].astype(v_w.dtype)
-            cl = _kv_write(cl, kind, k_w, v_w, jnp.zeros((), jnp.int32), cfg,
-                           cfg.window)
-            if kind == "local" and x.shape[1] > s_buf:
-                # ring-buffer invariant: position p lives at index p % s_buf.
-                # prefill wrote positions [S-s_buf, S) at [0, s_buf); roll so
-                # decode's pos % s_buf indexing lines up.
-                roll = (x.shape[1] - s_buf) % s_buf
-                cl = {kk: (jnp.roll(vv, roll, axis=1)
-                           if kk in ("k", "v", "k_s", "v_s") else vv)
-                      for kk, vv in cl.items()}
+            with jax.named_scope("attention"):
+                cl = _kv_write(cl, kind, k_w, v_w, jnp.zeros((), jnp.int32),
+                               cfg, cfg.window)
+                if kind == "local" and x.shape[1] > s_buf:
+                    # ring-buffer invariant: position p lives at index
+                    # p % s_buf.  prefill wrote positions [S-s_buf, S) at
+                    # [0, s_buf); roll so decode's pos % s_buf indexing
+                    # lines up.
+                    roll = (x.shape[1] - s_buf) % s_buf
+                    cl = {kk: (jnp.roll(vv, roll, axis=1)
+                               if kk in ("k", "v", "k_s", "v_s") else vv)
+                          for kk, vv in cl.items()}
             if cfg.encoder_decoder and "xattn" in p:
                 cl = dict(cl)
                 cl["xk"] = _proj(enc_out, p["xattn"]["wk"], approx_cfg,
@@ -1549,38 +1554,39 @@ def _paged_attn_block(p, x_t, cl, cfg, tables, seq_lens, active, *,
         posv = seq_lens[:, None]
         q = apply_rope(q, posv, cfg.rope_theta)
         k = apply_rope(k, posv, cfg.rope_theta)
-    bs = cl["k"].shape[1]
-    b_idx = jnp.arange(x_t.shape[0])
-    # the current token's K/V lands in the row's tail block; inactive
-    # rows scatter into the trash block (contents never read)
-    write_block = jnp.where(active, tables[b_idx, seq_lens // bs],
-                            TRASH_BLOCK)
-    write_off = seq_lens % bs
-    cl = dict(cl)
-    cl["k"] = cl["k"].at[write_block, write_off].set(
-        k[:, 0].astype(cl["k"].dtype))
-    cl["v"] = cl["v"].at[write_block, write_off].set(
-        v[:, 0].astype(cl["v"].dtype))
-    cache_len = seq_lens + 1
-    if backend == "pallas":
-        from repro.kernels.flash_attention.paged_attention import \
-            paged_decode_attention
-        attn = paged_decode_attention(
-            q, cl["k"], cl["v"], tables, cache_len,
-            logit_cap=cfg.attn_softcap, scale=cfg.query_scale,
-            interpret=cfg.mac_interpret)
-    else:
-        # gather-view decode: (B, P*bs, KV, hd) through the table, then
-        # the stock masked decode attention (bit-identical to the dense
-        # pool when P*bs matches its max_len — same shapes, same values:
-        # positions >= cache_len are masked to NEG_INF either way)
-        kc = jnp.reshape(cl["k"][tables],
-                         (x_t.shape[0], -1, cfg.n_kv_heads, cfg.head_dim))
-        vc = jnp.reshape(cl["v"][tables],
-                         (x_t.shape[0], -1, cfg.n_kv_heads, cfg.head_dim))
-        attn = decode_attention(q, kc, vc, cache_len, window=0,
-                                logit_cap=cfg.attn_softcap,
-                                scale=cfg.query_scale)
+    with jax.named_scope("attention"):
+        bs = cl["k"].shape[1]
+        b_idx = jnp.arange(x_t.shape[0])
+        # the current token's K/V lands in the row's tail block; inactive
+        # rows scatter into the trash block (contents never read)
+        write_block = jnp.where(active, tables[b_idx, seq_lens // bs],
+                                TRASH_BLOCK)
+        write_off = seq_lens % bs
+        cl = dict(cl)
+        cl["k"] = cl["k"].at[write_block, write_off].set(
+            k[:, 0].astype(cl["k"].dtype))
+        cl["v"] = cl["v"].at[write_block, write_off].set(
+            v[:, 0].astype(cl["v"].dtype))
+        cache_len = seq_lens + 1
+        if backend == "pallas":
+            from repro.kernels.flash_attention.paged_attention import \
+                paged_decode_attention
+            attn = paged_decode_attention(
+                q, cl["k"], cl["v"], tables, cache_len,
+                logit_cap=cfg.attn_softcap, scale=cfg.query_scale,
+                interpret=cfg.mac_interpret)
+        else:
+            # gather-view decode: (B, P*bs, KV, hd) through the table, then
+            # the stock masked decode attention (bit-identical to the dense
+            # pool when P*bs matches its max_len — same shapes, same values:
+            # positions >= cache_len are masked to NEG_INF either way)
+            kc = jnp.reshape(cl["k"][tables],
+                             (x_t.shape[0], -1, cfg.n_kv_heads, cfg.head_dim))
+            vc = jnp.reshape(cl["v"][tables],
+                             (x_t.shape[0], -1, cfg.n_kv_heads, cfg.head_dim))
+            attn = decode_attention(q, kc, vc, cache_len, window=0,
+                                    logit_cap=cfg.attn_softcap,
+                                    scale=cfg.query_scale)
     y = _attn_out(attn, p["attn"]["wo"], approx_cfg, cfg)
     if cfg.post_norm:
         y = _apply_norm(p["post1"], y, cfg)
@@ -1696,29 +1702,30 @@ def paged_prefill_chunk(params, cfg: ModelConfig, cache, tokens, *,
         if cfg.norm == "rms":
             q = apply_rope(q, positions, cfg.rope_theta)
             k = apply_rope(k, positions, cfg.rope_theta)
-        bs = cl["k"].shape[1]
-        blocks = jnp.where(jnp.arange(c_len) < count,
-                           row[tok_pos // bs], TRASH_BLOCK)
-        offs = tok_pos % bs
-        cl = dict(cl)
-        cl["k"] = cl["k"].at[blocks, offs].set(k[0].astype(cl["k"].dtype))
-        cl["v"] = cl["v"].at[blocks, offs].set(v[0].astype(cl["v"].dtype))
-        kc = jnp.reshape(cl["k"][row],
-                         (1, -1, cfg.n_kv_heads, cfg.head_dim))
-        vc = jnp.reshape(cl["v"][row],
-                         (1, -1, cfg.n_kv_heads, cfg.head_dim))
-        k_r = _repeat_kv(kc, cfg.n_heads // cfg.n_kv_heads)
-        v_r = _repeat_kv(vc, cfg.n_heads // cfg.n_kv_heads)
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                            k_r.astype(jnp.float32)) * scale
-        if cfg.attn_softcap > 0:
-            scores = softcap(scores, cfg.attn_softcap)
-        key_pos = jnp.arange(kc.shape[1])
-        valid = key_pos[None, :] <= tok_pos[:, None]       # (C, L)
-        scores = jnp.where(valid[None, None], scores, NEG_INF)
-        w = jax.nn.softmax(scores, axis=-1)
-        attn = jnp.einsum("bhqk,bkhd->bqhd", w,
-                          v_r.astype(jnp.float32)).astype(q.dtype)
+        with jax.named_scope("attention"):
+            bs = cl["k"].shape[1]
+            blocks = jnp.where(jnp.arange(c_len) < count,
+                               row[tok_pos // bs], TRASH_BLOCK)
+            offs = tok_pos % bs
+            cl = dict(cl)
+            cl["k"] = cl["k"].at[blocks, offs].set(k[0].astype(cl["k"].dtype))
+            cl["v"] = cl["v"].at[blocks, offs].set(v[0].astype(cl["v"].dtype))
+            kc = jnp.reshape(cl["k"][row],
+                             (1, -1, cfg.n_kv_heads, cfg.head_dim))
+            vc = jnp.reshape(cl["v"][row],
+                             (1, -1, cfg.n_kv_heads, cfg.head_dim))
+            k_r = _repeat_kv(kc, cfg.n_heads // cfg.n_kv_heads)
+            v_r = _repeat_kv(vc, cfg.n_heads // cfg.n_kv_heads)
+            scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                                k_r.astype(jnp.float32)) * scale
+            if cfg.attn_softcap > 0:
+                scores = softcap(scores, cfg.attn_softcap)
+            key_pos = jnp.arange(kc.shape[1])
+            valid = key_pos[None, :] <= tok_pos[:, None]       # (C, L)
+            scores = jnp.where(valid[None, None], scores, NEG_INF)
+            w = jax.nn.softmax(scores, axis=-1)
+            attn = jnp.einsum("bhqk,bkhd->bqhd", w,
+                              v_r.astype(jnp.float32)).astype(q.dtype)
         y = _attn_out(attn, p["attn"]["wo"], ac, cfg)
         if cfg.post_norm:
             y = _apply_norm(p["post1"], y, cfg)

@@ -143,6 +143,7 @@ from typing import Any, Callable, Mapping
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.approx_multiplier import N_CONFIGS
 from repro.core.controller import step_down_config
@@ -1065,7 +1066,8 @@ class Engine:
             return pool
 
         row = {k: v for k, v in row_cache.items() if k != "pos"}
-        self.cache = jax.tree.map(scatter, self.cache, row)
+        with TraceAnnotation("engine.prefill.scatter", blocks=len(blocks)):
+            self.cache = jax.tree.map(scatter, self.cache, row)
 
     def _preemption_victim(self) -> int | None:
         """Youngest in-flight request (latest submitted_at, ties toward
@@ -1102,13 +1104,15 @@ class Engine:
             req.status = "queued"
             self.queue.appendleft(req)
 
-    def _admit_paged(self) -> None:
+    def _admit_paged(self) -> int:
         """FIFO admission into free slots: reuse any cached prompt
         prefix (fork its blocks), reserve the first chunk's blocks, and
         register the request for chunked prefill.  Block shortage is a
-        head-of-line wait, like the power gate."""
+        head-of-line wait, like the power gate.  Returns the number of
+        requests admitted."""
         if self._draining:
-            return
+            return 0
+        admitted = 0
         p = self.paged
         bs = p.block_size
         for slot in range(self.max_batch):
@@ -1166,6 +1170,8 @@ class Engine:
                                             "next": start,
                                             "resumed": resumed}
             self.slots[slot] = req
+            admitted += 1
+        return admitted
 
     def _register_prefix_blocks(self, slot: int, toks: np.ndarray) -> None:
         """Publish the slot's shareable prompt blocks (whole prefill
@@ -1186,77 +1192,92 @@ class Engine:
         prefill + host scatter: bit-identical K/V to the dense engine);
         continuations run the paged chunk executable."""
         p = self.paged
-        bs, C = p.block_size, p.prefill_chunk
+        C = p.prefill_chunk
         for slot in sorted(self._prefill_progress):
             if slot not in self._prefill_progress:
                 continue       # preempted by an earlier slot this tick
             prog = self._prefill_progress[slot]
             toks, start = prog["tokens"], prog["next"]
             count = int(min(C, toks.size - start))
-            end = start + count
+            one_chunk = start == 0 and toks.size <= C
+            with TraceAnnotation("engine.prefill", req=self.slots[slot].rid,
+                                 slot=slot, start=start, count=count,
+                                 path="one_chunk" if one_chunk else "chunk"):
+                self._advance_prefill(slot, prog, start, count, one_chunk)
+
+    def _advance_prefill(self, slot: int, prog: dict, start: int, count: int,
+                         one_chunk: bool) -> None:
+        """One chunk of one slot's prefill (``_advance_prefills``)."""
+        p = self.paged
+        C = p.prefill_chunk
+        toks = prog["tokens"]
+        end = start + count
+        have = len(self._slot_blocks[slot])
+        need = p.blocks_for(end) - have
+        if need > 0:
+            # starved-pool escape (satellite fix): the decode path
+            # preempts the youngest request when it cannot get a
+            # write block (_ensure_write_blocks), but this path
+            # used to just wait — two mid-prefill slots that
+            # exhaust the pool then DEADLOCK forever, each holding
+            # blocks the other needs while no decode tick ever
+            # runs.  Preempt-by-recompute breaks the cycle; a slot
+            # never preempts itself (if it is the youngest, an
+            # older stuck slot's escape will preempt it instead)
+            while not self.allocator.can_alloc(need):
+                victim = self._preemption_victim()
+                if victim is None or victim == slot:
+                    break
+                self._preempt(victim)
+            if not self.allocator.can_alloc(need):
+                return                     # pool short; retry next tick
             have = len(self._slot_blocks[slot])
-            need = p.blocks_for(end) - have
-            if need > 0:
-                # starved-pool escape (satellite fix): the decode path
-                # preempts the youngest request when it cannot get a
-                # write block (_ensure_write_blocks), but this path
-                # used to just wait — two mid-prefill slots that
-                # exhaust the pool then DEADLOCK forever, each holding
-                # blocks the other needs while no decode tick ever
-                # runs.  Preempt-by-recompute breaks the cycle; a slot
-                # never preempts itself (if it is the youngest, an
-                # older stuck slot's escape will preempt it instead)
-                while not self.allocator.can_alloc(need):
-                    victim = self._preemption_victim()
-                    if victim is None or victim == slot:
-                        break
-                    self._preempt(victim)
-                if not self.allocator.can_alloc(need):
-                    continue               # pool short; retry next tick
-                have = len(self._slot_blocks[slot])
-                new = self.allocator.alloc_n(need)
-                self._slot_blocks[slot].extend(new)
-                self.block_tables[slot, have:have + need] = new
-            req = self.slots[slot]
-            cfg_vec = (self.slot_cfg[slot] if self.slot_pinned[slot]
-                       else self.approx_cfg)
-            acfg = self._replicate(cfg_vec)
-            buf = np.zeros((1, C), np.int32)
-            buf[0, :count] = toks[start:end]
-            tokens = self._replicate(jnp.asarray(buf))
-            if start == 0 and toks.size <= C:
-                logits, row_cache = self._prefill(
-                    self.params, tokens, acfg,
-                    jnp.asarray(count, jnp.int32))
-                self._scatter_prefill(slot, row_cache, count)
-            else:
-                logits, new_leaves = self._prefill_chunk(
-                    self.params, self._paged_operands(), tokens,
-                    jnp.asarray(slot, jnp.int32),
-                    jnp.asarray(start, jnp.int32),
-                    jnp.asarray(count, jnp.int32), acfg)
-                self.cache = new_leaves
-                # the chunk executable returns EVERY position's logits
-                # (the speculative verify consumes all rows); prefill
-                # completion samples from the last true one
-                logits = logits[:, count - 1]
-            self.n_prefill_tokens += count       # TRUE tokens advanced
-            self._count_energy(C, cfg_vec, "prefill",  # executed width
-                               cls=req.cls)
-            self.seq_lens[slot] = end
-            self.slot_pos[slot] = end
-            prog["next"] = end
-            if end == toks.size:
-                del self._prefill_progress[slot]
-                self._register_prefix_blocks(slot, toks)
-                if not prog["resumed"]:
+            new = self.allocator.alloc_n(need)
+            self._slot_blocks[slot].extend(new)
+            self.block_tables[slot, have:have + need] = new
+        req = self.slots[slot]
+        cfg_vec = (self.slot_cfg[slot] if self.slot_pinned[slot]
+                   else self.approx_cfg)
+        acfg = self._replicate(cfg_vec)
+        buf = np.zeros((1, C), np.int32)
+        buf[0, :count] = toks[start:end]
+        tokens = self._replicate(jnp.asarray(buf))
+        if one_chunk:
+            logits, row_cache = self._prefill(
+                self.params, tokens, acfg,
+                jnp.asarray(count, jnp.int32))
+            self._scatter_prefill(slot, row_cache, count)
+        else:
+            with TraceAnnotation("engine.operands"):
+                operands = self._paged_operands()
+            logits, new_leaves = self._prefill_chunk(
+                self.params, operands, tokens,
+                jnp.asarray(slot, jnp.int32),
+                jnp.asarray(start, jnp.int32),
+                jnp.asarray(count, jnp.int32), acfg)
+            self.cache = new_leaves
+            # the chunk executable returns EVERY position's logits
+            # (the speculative verify consumes all rows); prefill
+            # completion samples from the last true one
+            logits = logits[:, count - 1]
+        self.n_prefill_tokens += count       # TRUE tokens advanced
+        self._count_energy(C, cfg_vec, "prefill",  # executed width
+                           cls=req.cls)
+        self.seq_lens[slot] = end
+        self.slot_pos[slot] = end
+        prog["next"] = end
+        if end == toks.size:
+            del self._prefill_progress[slot]
+            self._register_prefix_blocks(slot, toks)
+            if not prog["resumed"]:
+                with TraceAnnotation("engine.prefill.sample", req=req.rid):
                     self.rng, k = jax.random.split(self.rng)
                     first = sample(logits, k,
                                    temperature=req.temperature)
                     req.tokens.append(int(first[0]))
-                    self.n_tokens_emitted += 1
-                if req.first_token_at is None:
-                    req.first_token_at = self.clock()
+                self.n_tokens_emitted += 1
+            if req.first_token_at is None:
+                req.first_token_at = self.clock()
 
     def _ensure_write_blocks(self, decodable: list[int]) -> list[int]:
         """Give every decode row a writable tail block for this tick's
@@ -1636,7 +1657,8 @@ class Engine:
                          or any(s is not None for s in self.slots))
         if now < self._backoff_until:
             return in_flight
-        self._admit_paged()
+        with TraceAnnotation("engine.admit") as span:
+            span.set_metadata(admitted=self._admit_paged())
         self._advance_prefills()
         active = self._ensure_write_blocks(
             [i for i, r in enumerate(self.slots)
@@ -1646,19 +1668,22 @@ class Engine:
                         or any(s is not None for s in self.slots))
         if self.spec is not None and self._spec_ok_paged(active):
             return self._spec_tick_paged(active, now, inj)
-        token = np.zeros((self.max_batch, 1), dtype=np.int32)
-        active_mask = np.zeros(self.max_batch, dtype=bool)
-        for i in active:
-            token[i, 0] = self.slots[i].tokens[-1]
-            active_mask[i] = True
-        pool_cfg = self._pool_cfg()
-        cache = self._paged_operands(active_mask)
-        token = self._replicate(token)
+        with TraceAnnotation("engine.operands"):
+            token = np.zeros((self.max_batch, 1), dtype=np.int32)
+            active_mask = np.zeros(self.max_batch, dtype=bool)
+            for i in active:
+                token[i, 0] = self.slots[i].tokens[-1]
+                active_mask[i] = True
+            pool_cfg = self._pool_cfg()
+            cache = self._paged_operands(active_mask)
+            token = self._replicate(token)
+            acfg = self._replicate(pool_cfg)
         try:
             if inj is not None:
                 inj.check_step_fail()
-            logits, new_leaves = self._decode(self.params, cache, token,
-                                              self._replicate(pool_cfg))
+            with TraceAnnotation("engine.decode", rows=len(active)):
+                logits, new_leaves = self._decode(self.params, cache, token,
+                                                  acfg)
             if inj is not None:
                 logits = inj.corrupt_logits(logits, active)
         except Exception as err:  # noqa: BLE001 — retry path, like _step
@@ -1668,8 +1693,10 @@ class Engine:
         # the scatters happened in the discarded new leaves and
         # seq_lens has not advanced, so the freshly ensured write
         # blocks are simply rewritten on the retry tick
-        rows = np.asarray(logits)
-        bad = [i for i in active if not np.isfinite(rows[i]).all()]
+        with TraceAnnotation("engine.logits_to_host", bytes=logits.nbytes):
+            rows = np.asarray(logits)
+        with TraceAnnotation("engine.finite_check"):
+            bad = [i for i in active if not np.isfinite(rows[i]).all()]
         if bad:
             self._quarantine(bad, pool_cfg)
             return True
@@ -1689,34 +1716,39 @@ class Engine:
             self.scheduler.on_step(self, active, cache, token,
                                    logits, pool_cfg,
                                    multiplicity=feedback)
-        self.rng, k = jax.random.split(self.rng)
-        temps = np.asarray([r.temperature if r is not None else 0.0
-                            for r in self.slots], np.float32)
-        greedy = np.asarray(jnp.argmax(logits, axis=-1).astype(jnp.int32))
-        if np.any(temps[active] > 0.0):
-            safe = np.where(temps > 0.0, temps, 1.0).astype(np.float32)
-            drawn = np.asarray(sample(
-                logits / jnp.asarray(safe)[:, None], k))
-            nxt = np.where(temps > 0.0, drawn, greedy)
-        else:
-            nxt = greedy
-        for i in active:
-            req = self.slots[i]
-            self.seq_lens[i] += 1
-            req.tokens.append(int(nxt[i]))
-            self.n_tokens_emitted += 1
-            self.slot_pos[i] += 1
-            if (len(req.tokens) >= req.max_new_tokens
-                    or self.slot_pos[i] >= self.max_len - 1):
-                req.done = True
-                req.status = "done"
-                req.finished_at = self.clock()
-                # repro-lint: disable=bounded-state — completed holds the run()'s return payload, one entry per submitted request; bounding it would silently drop finished results
-                self.completed.append(req)
-                self.slots[i] = None
-                self._nan_strikes[i] = 0
-                self._release_slot(i)
-                self.slot_pos[i] = 0
+        with TraceAnnotation("engine.sample"):
+            self.rng, k = jax.random.split(self.rng)
+            temps = np.asarray([r.temperature if r is not None else 0.0
+                                for r in self.slots], np.float32)
+            greedy = np.asarray(
+                jnp.argmax(logits, axis=-1).astype(jnp.int32))
+            if np.any(temps[active] > 0.0):
+                safe = np.where(temps > 0.0, temps, 1.0).astype(np.float32)
+                drawn = np.asarray(sample(
+                    logits / jnp.asarray(safe)[:, None], k))
+                nxt = np.where(temps > 0.0, drawn, greedy)
+            else:
+                nxt = greedy
+        with TraceAnnotation("engine.commit") as span:
+            n_done = len(self.completed)
+            for i in active:
+                req = self.slots[i]
+                self.seq_lens[i] += 1
+                req.tokens.append(int(nxt[i]))
+                self.n_tokens_emitted += 1
+                self.slot_pos[i] += 1
+                if (len(req.tokens) >= req.max_new_tokens
+                        or self.slot_pos[i] >= self.max_len - 1):
+                    req.done = True
+                    req.status = "done"
+                    req.finished_at = self.clock()
+                    # repro-lint: disable=bounded-state — completed holds the run()'s return payload, one entry per submitted request; bounding it would silently drop finished results
+                    self.completed.append(req)
+                    self.slots[i] = None
+                    self._nan_strikes[i] = 0
+                    self._release_slot(i)
+                    self.slot_pos[i] = 0
+            span.set_metadata(finished=len(self.completed) - n_done)
         if (self.snapshot_every and self.checkpointer is not None
                 and self.n_decode_steps % self.snapshot_every == 0):
             self.save_snapshot()
@@ -1729,7 +1761,7 @@ class Engine:
         """One engine tick: admit requests, one decode step for the pool.
         Runs under the sharding mapping's mesh context when one is
         attached (a no-op single-host otherwise)."""
-        with self._ctx():
+        with self._ctx(), TraceAnnotation("engine.tick"):
             return self._step()
 
     def _step(self):
