@@ -27,14 +27,18 @@ Three kernel variants share the truncation body:
     the f32 rescale epilogue run INSIDE the kernel, so a float-in /
     float-out approx dense is one pallas_call — no int8 activation or
     int32 accumulator tensor ever round-trips through HBM.
-  * ``approx_mac_grouped_matmul`` — the fused variant with a leading
+  * ``approx_mac_bank_matmul`` — the fused variant with a leading
     EXPERT grid axis (DESIGN.md §4): one pallas_call computes E
-    independent GEMMs against a stacked (E, K, N) weight bank — the MoE
-    expert loop folded into the kernel grid, no per-expert dispatch.
-    Per-expert valid-row counts ride as scalar-prefetch metadata so
-    empty / ragged expert slices skip their MXU work, and the config
-    operand widens to (E, n_blocks, 4) — the error knob becomes
-    per-EXPERT (x per-neuron-block) inside one compiled kernel.
+    independent GEMMs against one layer of a stacked (L, E, K, N)
+    weight bank — the MoE expert loop folded into the kernel grid, no
+    per-expert dispatch.  The layer index is a scalar-prefetch operand
+    of the weight BlockSpec's index map, so a scan-stacked bank is read
+    in place (no per-layer slice or relayout); a lone (E, K, N) bank is
+    the L = 1 case.  Per-expert valid-row counts ride as scalar-prefetch
+    metadata so empty / ragged expert slices skip their MXU work, the
+    config operand widens to (E, n_blocks, 4) — the error knob becomes
+    per-EXPERT (x per-neuron-block) inside one compiled kernel — and
+    config 0 skips the truncation with a scalar branch.
 
 Tiling: grid (M/bm, N/bn, K/bk), A tile (bm, bk) and B tile (bk, bn) in
 VMEM, int32 accumulator scratch (bm, bn).  bm = bn = 128 and bk = 256
@@ -250,43 +254,8 @@ def approx_mac_fused_matmul(x, w_q, scale_row, x_scale, config=0, *,
 
 
 # ---------------------------------------------------------------------------
-# grouped (MoE expert-bank) variant
+# grouped (MoE expert-bank) variant, one layer of an (L, E, K, N) bank
 # ---------------------------------------------------------------------------
-
-def _grouped_kernel(cfg_ref, rows_ref, xscale_ref, x_ref, b_ref, scale_ref,
-                    o_ref, acc_ref, *, k_steps, bm):
-    """One (expert, m-block, n-block, k-step) grid cell of the grouped
-    fused GEMM.  cfg_ref: (E, n_blocks, 4) SMEM — expert e's n-block j
-    runs its own (depth_a, depth_b, gate, rtn); rows_ref: (E,) SMEM
-    valid-row counts — an m-block with no valid row skips the MXU work
-    entirely (its accumulator stays zero, so the epilogue writes zeros:
-    exactly what computing the zero-masked rows would produce).
-    scale_ref carries the COMBINED x_scale * w_scale rows (one rounding
-    in the wrapper, one association-free epilogue multiply here — see
-    _fused_kernel)."""
-    e, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-
-    @pl.when(pl.program_id(3) == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when(rows_ref[e] > i * bm)
-    def _mac():
-        x_scale = xscale_ref[0]
-        depth_a, depth_b = cfg_ref[e, j, 0], cfg_ref[e, j, 1]
-        gate, rtn = cfg_ref[e, j, 2], cfg_ref[e, j, 3]
-        x_q = jnp.clip(jnp.round(x_ref[0] / x_scale), -QMAX, QMAX
-                       ).astype(jnp.int8)
-        a = _truncate(x_q, depth_a, gate, rtn)
-        b = _truncate(b_ref[0], depth_b, gate, rtn)
-        acc_ref[...] += jax.lax.dot_general(
-            a, b, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)
-
-    @pl.when(pl.program_id(3) == k_steps - 1)
-    def _done():
-        o_ref[0] = acc_ref[...].astype(jnp.float32) * scale_ref[0]
-
 
 def grouped_config_operand(config, n_experts: int,
                            n_blocks: int = 1) -> jax.Array:
@@ -313,46 +282,113 @@ def grouped_config_operand(config, n_experts: int,
                             (n_experts, n_blocks, 4))
 
 
-def approx_mac_grouped_matmul(x, w_q, scale_rows, x_scale, group_rows,
-                              config=0, *, bm: int = 128, bn: int = 128,
-                              bk: int = 256, interpret: bool = False):
-    """Grouped fused approx GEMM over an expert bank: ONE pallas_call.
+def _approx_mac_bank_kernel(cfg_ref, rows_ref, layer_ref, xscale_ref, x_ref,
+                            b_ref, scale_ref, o_ref, acc_ref, *, k_steps,
+                            bm):
+    """One (expert, m-block, n-block, k-step) grid cell of the grouped
+    fused GEMM against layer ``layer_ref[0]`` of a stacked bank.
+    cfg_ref: (E, n_blocks, 4) SMEM — expert e's n-block j runs its own
+    (depth_a, depth_b, gate, rtn); rows_ref: (E,) SMEM valid-row counts
+    — an m-block with no valid row skips the MXU work entirely (its
+    accumulator stays zero, so the epilogue writes zeros: exactly what
+    computing the zero-masked rows would produce).  A weight depth of 0
+    (config 0; the activation depth is then 0 too) skips both
+    truncations — the same bits, without the VPU pass.  The activation
+    tile arrives in the caller's dtype (its f32 conversion is exact) and
+    the product leaves in ``o_ref``'s.  scale_ref carries the COMBINED
+    x_scale * w_scale rows (one rounding in the wrapper, one
+    association-free epilogue multiply here — see _fused_kernel)."""
+    e, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
-    x: (E, M, K) f32 per-expert activation slices (pre-padded; rows at
-    index >= group_rows[e] must be zero — ops masks them); w_q:
-    (E, K, N) int8 stacked weight bank; scale_rows: (E, N) f32 COMBINED
-    dequant scales (x_scale * per-expert per-column w_scale, rounded
-    once by the caller); x_scale: (1,) f32 shared per-tensor activation
-    scale (for the in-kernel quantize); group_rows: (E,) int32 valid-row
-    counts (ragged/empty experts skip their m-blocks); config: see
-    grouped_config_operand.  Returns (E, M, N) f32 — E dequantized
-    approximate products from one kernel launch, each expert (and each
-    of its N-blocks) at its own error config.  Grid (E, M/bm, N/bn,
-    K/bk); the expert axis is just the outermost parallel grid
-    dimension, so folding the expert loop into the kernel costs no extra
-    HBM traffic and no per-expert dispatch."""
+    @pl.when(pl.program_id(3) == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    depth_a, depth_b = cfg_ref[e, j, 0], cfg_ref[e, j, 1]
+    gate, rtn = cfg_ref[e, j, 2], cfg_ref[e, j, 3]
+    live = rows_ref[e] > i * bm
+
+    def mac(a, b):
+        acc_ref[...] += jax.lax.dot_general(
+            a, b, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32)
+
+    def x_q():
+        return jnp.clip(jnp.round(x_ref[...].astype(jnp.float32)
+                                  / xscale_ref[0]), -QMAX, QMAX
+                        ).astype(jnp.int8)
+
+    @pl.when(live & (depth_b == 0))
+    def _exact():
+        mac(x_q(), b_ref[...])
+
+    @pl.when(live & (depth_b != 0))
+    def _approx():
+        mac(_truncate(x_q(), depth_a, gate, rtn),
+            _truncate(b_ref[...], depth_b, gate, rtn))
+
+    @pl.when(pl.program_id(3) == k_steps - 1)
+    def _done():
+        o_ref[...] = (acc_ref[...].astype(jnp.float32) * scale_ref[...]
+                      ).astype(o_ref.dtype)
+
+
+def approx_mac_bank_matmul(x, bank, layer, scale_rows, x_scale, group_rows,
+                           config=0, *, bm: int, bn: int, bk: int,
+                           out_dtype=jnp.float32, interpret: bool = False):
+    """Grouped fused approx GEMM over one layer of an expert bank: ONE
+    pallas_call.
+
+    x: (E, M, K) per-expert activation slices (pre-padded to bm; rows at
+    index >= group_rows[e] must be zero — ops masks them); bank:
+    (L, E, K, N) int8, every layer's bank, of which the weight
+    BlockSpec's index map reads layer ``layer`` (a scalar-prefetch
+    operand) — no per-layer slice, relayout or truncated copy of the
+    bank is ever made; scale_rows: (E, N) f32 COMBINED dequant scales
+    (x_scale * per-expert per-column w_scale, rounded once by the
+    caller); x_scale: shared per-tensor activation scale (for the
+    in-kernel quantize); group_rows: (E,) int32 valid-row counts
+    (ragged/empty experts skip their m-blocks); config: see
+    grouped_config_operand.  K and N must be whole multiples of bk and
+    bn.  Returns (E, M, N) `out_dtype` — E dequantized approximate
+    products, each expert (and each of its N-blocks) at its own error
+    config.  Grid (E, M/bm, N/bn, K/bk); the expert axis is just the
+    outermost parallel grid dimension."""
     e, m, k = x.shape
-    e2, k2, n = w_q.shape
+    _, e2, k2, n = bank.shape
     assert e == e2 and k == k2 and scale_rows.shape == (e, n), \
-        (x.shape, w_q.shape, scale_rows.shape)
+        (x.shape, bank.shape, scale_rows.shape)
     assert m % bm == 0 and n % bn == 0 and k % bk == 0, \
         (m, n, k, bm, bn, bk)
     k_steps = k // bk
-    kernel = lambda *refs: _grouped_kernel(*refs, k_steps=k_steps, bm=bm)
-    call = _grid_call(
-        kernel, 3, (e, m // bm, n // bn, k_steps),
-        ([
-            pl.BlockSpec((1, bm, bk), lambda g, i, j, ks: (g, i, ks)),
-            pl.BlockSpec((1, bk, bn), lambda g, i, j, ks: (g, ks, j)),
-            # (E, 1, N): a (1, bn) block of an (E, N) array would tile
-            # the expert axis by 1, which Mosaic refuses
-            pl.BlockSpec((1, 1, bn), lambda g, i, j, ks: (g, 0, j)),
-        ], pl.BlockSpec((1, bm, bn), lambda g, i, j, ks: (g, i, j))),
-        jax.ShapeDtypeStruct((e, m, n), jnp.float32),
-        [pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret,
+    kernel = lambda *refs: _approx_mac_bank_kernel(*refs, k_steps=k_steps,
+                                                   bm=bm)
+    # index maps get the grid indices, then the four prefetch refs
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(e, m // bm, n // bn, k_steps),
+        in_specs=[
+            pl.BlockSpec((None, bm, bk),
+                         lambda g, i, j, ks, *_: (g, i, ks)),
+            pl.BlockSpec((None, None, bk, bn),
+                         lambda g, i, j, ks, c, r, lyr, s: (lyr[0], g, ks,
+                                                            j)),
+            pl.BlockSpec((None, 1, bn), lambda g, i, j, ks, *_: (g, 0, j)),
+        ],
+        out_specs=pl.BlockSpec((None, bm, bn),
+                               lambda g, i, j, ks, *_: (g, i, j)),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
     )
+    call = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((e, m, n), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3 + ("arbitrary",)),
+        # the compiled custom call takes this name: the benchmark's trace
+        # reduction (bench/trace.py is_gemm) counts it as GEMM time by it
+        interpret=interpret, name="approx_mac_bank")
     return call(grouped_config_operand(config, e, n // bn),
                 jnp.asarray(group_rows, jnp.int32).reshape(e),
+                jnp.asarray(layer, jnp.int32).reshape(1),
                 jnp.asarray(x_scale, jnp.float32).reshape(1),
-                x.astype(jnp.float32), w_q, scale_rows.reshape(e, 1, n))
+                x, bank, scale_rows.reshape(e, 1, n))

@@ -17,9 +17,11 @@ Both accept per-N-column-block config vectors (the per-neuron knob); see
 ``approx_dense_grouped_pallas`` is the grouped-expert twin (DESIGN.md
 §4): E GEMMs against a stacked (E, K, N) QTensor bank in ONE
 pallas_call, per-expert(-per-block) configs and ragged/empty expert
-slices included.  ``autotune_block_shapes`` sweeps (bm, bn, bk)
-candidates for a GEMM shape and returns the measured ranking
-(BENCH_pallas_path.json).
+slices included; ``approx_dense_bank_pallas`` runs them against one
+layer of a scan-stacked (L, E, K, N) bank in place, with tiles from the
+GEMM's shape (``bank_block_shapes``); both run the one grouped kernel.
+``autotune_block_shapes`` sweeps (bm, bn, bk) candidates for a dense
+GEMM shape and returns the measured ranking (BENCH_pallas_path.json).
 """
 from __future__ import annotations
 
@@ -32,7 +34,7 @@ import jax.numpy as jnp
 from repro.core.quantization import (QMAX, QTensor, compute_scale,
                                      expand_left)
 
-from .approx_mac import (approx_mac_fused_matmul, approx_mac_grouped_matmul,
+from .approx_mac import (approx_mac_bank_matmul, approx_mac_fused_matmul,
                          approx_mac_matmul)
 
 
@@ -215,27 +217,27 @@ def approx_dense_pallas(x, w_q, w_scale=None, config=0, *,
 
 
 @partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def _approx_grouped_fused_jit(x, w_q, w_scale, config, group_rows,
-                              x_scale, *, bm, bn, bk, interpret):
-    assert w_q.dtype == jnp.int8
+def _approx_mac_grouped_jit(x, bank, layer, w_scale, config, group_rows,
+                            x_scale, *, bm, bn, bk, interpret):
+    """E GEMMs of x (E, M, K) against layer `layer` of an (L, E, K, N)
+    (or a lone (E, K, N)) int8 bank -> (E, M, N) in x's dtype.  Its name
+    carries ``approx_mac``: the compiled kernel's instruction is named
+    after it, and the benchmark counts GEMM time by that
+    (bench/trace.py)."""
+    assert bank.dtype == jnp.int8
+    if bank.ndim == 3:
+        bank = bank[None]      # a lone (E, K, N) bank: a one-layer stack
     e, m, k = x.shape
-    n = w_q.shape[-1]
-    # auto-shrink blocks to the hardware-granularity-rounded dims: a
-    # per-expert slice smaller than the tile would otherwise pad every
-    # expert's quantize + MAC up to full (bm, bk) tiles — pure waste, on
-    # TPU (DMA + MXU occupancy) and in interpret mode alike.  Results
-    # are tiling-invariant, and bn can only shrink when the GEMM has a
-    # single N-block, so neuron-group semantics are unchanged.
-    bm = min(bm, -(-m // 8) * 8)
-    bk = min(bk, -(-k // 128) * 128)
-    bn = min(bn, -(-n // 128) * 128)
-    x2 = _pad_to(_pad_to(x.astype(jnp.float32), bm, 1), bk, 2)
-    w2 = _pad_to(_pad_to(w_q, bk, 1), bn, 2)
+    n = bank.shape[-1]
+    x2 = _pad_to(_pad_to(x, bm, 1), bk, 2)
+    # a no-op where the blocks divide the bank (a scan-stacked bank's
+    # tiles always do: see bank_block_shapes)
+    w2 = _pad_to(_pad_to(bank, bk, 2), bn, 3)
     # combined dequant scale, rounded once (see _approx_dense_fused_jit)
     ws = _pad_to(x_scale * jnp.broadcast_to(
         jnp.asarray(w_scale, jnp.float32).reshape(
             (e, -1) if jnp.ndim(w_scale) >= 1 else (1, 1)), (e, n)), bn, 1)
-    n_blocks = w2.shape[2] // bn
+    n_blocks = w2.shape[-1] // bn
     if config.ndim == 2:
         # per-expert neuron-GROUP vectors: expand each expert's row onto
         # the block grid with the same conservative lowest-MRED collapse
@@ -243,9 +245,9 @@ def _approx_grouped_fused_jit(x, w_q, w_scale, config, group_rows,
         # _expand_group_vector's fast path keeps exact per-block rows)
         config = jax.vmap(
             lambda c: _expand_group_vector(c, n, bn, n_blocks))(config)
-    out = approx_mac_grouped_matmul(x2, w2, ws, x_scale, group_rows,
-                                    config, bm=bm, bn=bn, bk=bk,
-                                    interpret=interpret)
+    out = approx_mac_bank_matmul(x2, w2, layer, ws, x_scale, group_rows,
+                                 config, bm=bm, bn=bn, bk=bk,
+                                 out_dtype=x.dtype, interpret=interpret)
     return out[:, :m, :n]
 
 
@@ -291,9 +293,77 @@ def approx_dense_grouped_pallas(x, w_q, w_scale=None, config=0,
     # dispatch buffer where the comparison path does it — see the note
     # in approx_dense_pallas on XLA's constant-division rewrite)
     x_scale = compute_scale(x)
-    y = _approx_grouped_fused_jit(x, w_q, w_scale, config, rows, x_scale,
-                                  bm=bm, bn=bn, bk=bk, interpret=interpret)
+    # auto-shrink blocks to the hardware-granularity-rounded dims: a
+    # per-expert slice smaller than the tile would otherwise pad every
+    # expert's quantize + MAC up to full (bm, bk) tiles — pure waste, on
+    # TPU (DMA + MXU occupancy) and in interpret mode alike.  Results
+    # are tiling-invariant, and bn can only shrink when the GEMM has a
+    # single N-block, so neuron-group semantics are unchanged.
+    n = w_q.shape[-1]
+    bm = min(bm, -(-m // 8) * 8)
+    bk = min(bk, -(-x.shape[-1] // 128) * 128)
+    bn = min(bn, -(-n // 128) * 128)
+    y = _approx_mac_grouped_jit(x, w_q, 0, w_scale, config, rows,
+                                x_scale, bm=bm, bn=bn, bk=bk,
+                                interpret=interpret)
     return y.astype(compute_dtype)
+
+
+# largest int8 weight tile (bk * bn bytes) the in-place bank kernel
+# DMAs per grid step (OLMoE's whole (2048, 1024) expert matrices)
+BANK_TILE_BYTES = 2 << 20
+
+
+def bank_block_shapes(m: int, k: int, n: int, sublane: int = 8):
+    """(bm, bn, bk) of the in-place bank kernel, from the GEMM's shape.
+
+    One m-block per expert up to 256 rows (rows rounded up to the
+    activation dtype's `sublane` tile: 128 decode rows stay 128, a
+    prefill chunk's 40 become 48 in bf16), the whole contraction, and
+    the output width halved (then the contraction) until the weight
+    tile fits BANK_TILE_BYTES: few, large grid steps, since every step
+    costs a fixed overhead and the weight DMA is the kernel's work."""
+    nm = -(-m // 256)
+    bm = -(-(-(-m // nm)) // sublane) * sublane
+    bk, bn = k, n
+    while bk * bn > BANK_TILE_BYTES and bn % 256 == 0:
+        bn //= 2
+    while bk * bn > BANK_TILE_BYTES and bk % 256 == 0:
+        bk //= 2
+    return bm, bn, bk
+
+
+def approx_dense_bank_pallas(x, bank, layer, w_scale, config, group_rows,
+                             *, interpret: bool = False):
+    """Grouped-expert approx GEMM against layer `layer` of a stacked
+    (L, E, K, N) int8 bank, read in place: ONE pallas_call.
+
+    x: (E, M, K) per-expert activation slices (rows at index >=
+    group_rows[e], the (E,) valid-row counts, must be zero: they skip
+    their MXU work); w_scale:
+    (E, N) this layer's per-expert per-column scales; config: a scalar,
+    an (E,) vector or an (E, g) matrix, as approx_dense_grouped_pallas.
+    Tiles come from bank_block_shapes.  Returns (E, M, N) in x's dtype,
+    bit-identical (interpret mode) to the XLA expert einsum on the same
+    shared per-tensor activation scale."""
+    _, m, k = x.shape
+    n = bank.shape[-1]
+    config = jnp.asarray(config, jnp.int32)
+    bm, bn, bk = bank_block_shapes(m, k, n,
+                                   8 * 4 // jnp.dtype(x.dtype).itemsize)
+    if config.ndim == 2 and config.shape[1] > 1 and n % 128 == 0:
+        # neuron groups: column blocks no wider than a group (down to
+        # 128), so the groups resolve as on the grouped kernel's grid
+        bn = min(bn, max(128, n // config.shape[1] // 128 * 128))
+        while n % bn:
+            bn -= 128
+    # the shared activation scale, in the caller's compilation context
+    # (see approx_dense_pallas)
+    x_scale = compute_scale(x.astype(jnp.float32))
+    return _approx_mac_grouped_jit(x, bank, layer, w_scale, config,
+                                   jnp.asarray(group_rows, jnp.int32),
+                                   x_scale, bm=bm, bn=bn, bk=bk,
+                                   interpret=interpret)
 
 
 DEFAULT_BLOCK_CANDIDATES = (

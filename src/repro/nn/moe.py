@@ -28,6 +28,15 @@ Expert weights may arrive pre-quantized (``quantize_lm_params`` builds
 the stacked bank once at engine init); float weights are bank-quantized
 per trace with the same per-expert per-output-channel scales.
 
+In-place banks (DESIGN.md §4): the scanned layer stack hands each layer
+the whole (L, E, K, N) bank as a ``LayerBank``; on a TPU each expert
+GEMM of the quantized path then runs as ONE grouped-kernel pallas_call
+that reads the layer's tiles straight out of the bank, whatever
+``backend`` says (it picks the dense GEMMs' path).  The choice is made
+when the program is lowered (``lax.platform_dependent``); elsewhere the
+layer is sliced out and takes the path above.  ``count_expert_gemms``
+tallies the GEMMs a call runs on each path (``Engine.expert_gemm_paths``).
+
 Expert parallelism (``ep=True`` / ModelConfig.moe_ep): expert weights and
 the dispatch buffer shard on the "model" axis instead of TP-on-d_ff, and
 the gather-free combine reduces the per-layer collective to one
@@ -46,11 +55,88 @@ output bit-identical to single-host (tests/test_sharded_serving.py).
 """
 from __future__ import annotations
 
+import contextlib
+from collections import Counter
+from dataclasses import dataclass
+from typing import Any
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .layers import ACT
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclass
+class LayerBank:
+    """Layer ``layer`` of a scan-stacked expert bank, handed to the scan
+    body whole: ``bank`` is every layer's (L, E, K, N) int8 values,
+    ``scale`` this layer's (E, N) per-expert per-column scales.  On a
+    TPU the expert GEMM reads the layer's tiles in place through the
+    bank kernel; elsewhere ``take`` slices the layer out, as the scan
+    would have (transformer._scan_layer_groups)."""
+    bank: Any
+    scale: Any
+    layer: Any            # int32 scalar (traced) or Python int
+    axis: int = 1         # the QTensor channel axis of one expert's (K, N)
+
+    def take(self):
+        from repro.core.quantization import QTensor
+        return QTensor(self.bank[self.layer], self.scale, self.axis)
+
+    def tree_flatten(self):
+        return (self.bank, self.scale, self.layer), (self.axis,)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children, aux[0])
+
+
+# Expert GEMMs by path, tallied while a program is traced: one
+# (platform the program is lowered for, tally) per open
+# count_expert_gemms block, and how many times the innermost traced body
+# runs per call.
+_TALLIES: list[tuple[str, Counter]] = []
+_REPEATS: list[int] = [1]
+
+
+@contextlib.contextmanager
+def count_expert_gemms(platform: str):
+    """Tally, by path, the expert GEMMs a call runs of a program traced
+    inside the block and lowered for `platform` ("tpu", "cpu", ...)."""
+    tally: Counter = Counter()
+    _TALLIES.append((platform, tally))
+    try:
+        yield tally
+    finally:
+        _TALLIES.pop()
+
+
+@contextlib.contextmanager
+def repeated(n: int):
+    """A body traced once and run `n` times (a scan, a lax.map): each
+    GEMM traced inside counts `n` times."""
+    _REPEATS.append(_REPEATS[-1] * n)
+    try:
+        yield
+    finally:
+        _REPEATS.pop()
+
+
+def _count(on_tpu: str, elsewhere: str | None = None) -> None:
+    """One expert GEMM, on path `on_tpu` in a program lowered for a TPU
+    and on `elsewhere` (default the same) in any other."""
+    for platform, tally in _TALLIES:
+        path = on_tpu if platform == "tpu" else elsewhere or on_tpu
+        tally[path] += _REPEATS[-1]
+
+
+def _by_platform(h, w, *, tpu, default):
+    """`tpu(h, w)` in a program lowered for a TPU, `default(h, w)` in any
+    other (the CPU tests replace it to run the TPU branch, interpreted,
+    off a TPU)."""
+    return jax.lax.platform_dependent(h, w, tpu=tpu, default=default)
 
 
 def router_probs(x, w_router, *, dtype=jnp.float32):
@@ -104,7 +190,8 @@ def moe_ffn(x, params, *, n_experts: int, top_k: int,
         if unroll_chunks:
             yc = jnp.stack([one(xc[i]) for i in range(seq_chunks)])
         else:
-            yc = jax.lax.map(one, xc)
+            with repeated(seq_chunks):
+                yc = jax.lax.map(one, xc)
         yg = yc.swapaxes(0, 1).reshape(g, sg, d)
         from repro.dist.sharding import lsc as _lsc
         yg = _lsc(yg, "batch", None, None)
@@ -187,68 +274,36 @@ def _moe_groups(xg, params, *, n_experts: int, top_k: int,
     # default is the grouped kernel — every expert's GEMM in one
     # pallas_call; grouped=False is the per-expert lax.map A/B path,
     # bit-identical by construction.
+    #
+    # A layer of a scan-stacked bank (LayerBank) on the quantized path
+    # runs, on a TPU, as the in-place bank kernel: one pallas_call that
+    # reads the layer's (E, K, N) tiles straight out of the (L, E, K, N)
+    # bank and truncates them in VMEM.  `rows` bounds each expert's
+    # valid rows in the kernel's (E, G*C) row layout; past it the
+    # dispatch buffer is zero and the kernel skips the MXU work.
+    quantized = isinstance(approx_cfg, jax.Array) or approx_cfg > 0
+    kept = jnp.minimum(counts, cap)                          # (G,E)
+    rows = jnp.max(jnp.where(kept > 0, jnp.arange(g)[:, None] * cap + kept,
+                             0), axis=0)                     # (E,)
+    einsum_path = ("xla_einsum" if not quantized or backend != "pallas"
+                   else "grouped_kernel" if grouped else "expert_map")
+
     @jax.named_scope("gemm")
     def expert_einsum(h, w, spec):
-        from repro.core.quantization import QTensor
-        vector_cfg = isinstance(approx_cfg, jax.Array) and approx_cfg.ndim >= 1
-        if isinstance(approx_cfg, jax.Array) or approx_cfg > 0:
-            from repro.core.quantization import quantize, truncate_operand_lsb
-            from repro.core.approx_matmul import resolve_operand_params
-            if backend == "pallas":
-                # spec is per-expert (G,C,K)@(K,N): expert axis to front,
-                # flatten (G,C) into M
-                assert spec in ("gecd,edf->gecf", "gecf,efd->gecd"), spec
-                w_qt = quantize_expert_bank(w)
-                he = jnp.moveaxis(h.astype(jnp.float32), 1, 0)  # (E,G,C,K)
-                e_, g_, c_, k_ = he.shape
-                he = he.reshape(e_, g_ * c_, k_)
-                cfg = approx_cfg
-                if isinstance(cfg, jax.Array) and cfg.ndim == 1:
-                    # (g,) per-neuron-group vector shared by every expert
-                    cfg = jnp.broadcast_to(cfg[None, :],
-                                           (e_,) + cfg.shape)
-                if grouped:
-                    from repro.kernels.approx_mac.ops import \
-                        approx_dense_grouped_pallas
-                    y = approx_dense_grouped_pallas(
-                        he, w_qt, config=cfg, interpret=interpret,
-                        compute_dtype=jnp.float32)
-                else:
-                    # per-expert lax.map A/B path — one kernel call per
-                    # expert on the same bank + shared activation scale
-                    from repro.kernels.approx_mac.ops import approx_mac
-                    h_qt = quantize(he)
-                    cfg_e = jnp.broadcast_to(
-                        jnp.asarray(cfg, jnp.int32),
-                        (e_,) + jnp.shape(cfg)[1:])
-                    acc = jax.lax.map(
-                        lambda ew: approx_mac(ew[0], ew[1], ew[2],
-                                              interpret=interpret),
-                        (h_qt.values, w_qt.values, cfg_e))
-                    # combined scale rounded once — the shared rescale
-                    # convention (see core.approx_matmul.approx_dense)
-                    y = acc.astype(jnp.float32) \
-                        * (h_qt.scale * w_qt.scale[:, None, :])
-                return jnp.moveaxis(y.reshape(e_, g_, c_, -1), 0, 1
-                                    ).astype(h.dtype)       # (G,E,C,N)
-            assert not vector_cfg, \
-                "per-block/per-expert config vectors require backend='pallas'"
-            h_qt = quantize(h.astype(jnp.float32))
-            # the SAME bank quantization as the pallas path (per-expert
-            # per-output-channel scales), so float and pre-quantized
-            # parameters are bit-identical on this backend too
-            w_qt = quantize_expert_bank(w)
-            w_scale = w_qt.scale[None, :, None, :]          # (1,E,1,N)
-            d_a, d_b, gate_th, rtn = resolve_operand_params(approx_cfg)
-            hq = truncate_operand_lsb(h_qt.values, d_a, gate_th, rtn)
-            wq = truncate_operand_lsb(w_qt.values, d_b, gate_th, rtn)
-            acc = jnp.einsum(spec, hq.astype(jnp.int32),
-                             wq.astype(jnp.int32))
-            return (acc.astype(jnp.float32) * (h_qt.scale * w_scale)
-                    ).astype(h.dtype)
-        if isinstance(w, QTensor):
-            w = w.dequantize()
-        return jnp.einsum(spec, h, w.astype(h.dtype))
+        from repro.dist.sharding import current_mapping
+        if isinstance(w, LayerBank):
+            if quantized and grouped and current_mapping() is None:
+                _count("bank_kernel", einsum_path)
+                return _by_platform(
+                    h, w,
+                    tpu=lambda h, w: _bank_gemm(h, w, approx_cfg, rows,
+                                                interpret),
+                    default=lambda h, w: _einsum(h, w.take(), spec,
+                                                 approx_cfg, backend,
+                                                 interpret, grouped))
+            w = w.take()
+        _count(einsum_path)
+        return _einsum(h, w, spec, approx_cfg, backend, interpret, grouped)
 
     if "w_gate" in params:
         gate = expert_einsum(he, params["w_gate"], "gecd,edf->gecf")
@@ -278,6 +333,88 @@ def _moe_groups(xg, params, *, n_experts: int, top_k: int,
     yg = jax.vmap(lambda y, ti, w: y.at[ti].add(w))(yg, tok_slot, contrib)
     yg = lsc(yg, "batch", None, None)
     return yg, {"router_probs_mean": jnp.mean(probs, axis=(0, 1))}
+
+
+def _einsum(h, w, spec, approx_cfg, backend: str, interpret: bool,
+            grouped: bool):
+    """(G, E, C, K) @ one layer's (E, K, N) expert weights -> (G, E, C, N)
+    in h's dtype: the XLA einsum (operand truncation as XLA ops) or, on
+    the "pallas" backend, the grouped / per-expert kernels."""
+    from repro.core.quantization import QTensor
+    vector_cfg = isinstance(approx_cfg, jax.Array) and approx_cfg.ndim >= 1
+    if isinstance(approx_cfg, jax.Array) or approx_cfg > 0:
+        from repro.core.quantization import quantize, truncate_operand_lsb
+        from repro.core.approx_matmul import resolve_operand_params
+        if backend == "pallas":
+            # spec is per-expert (G,C,K)@(K,N): expert axis to front,
+            # flatten (G,C) into M
+            assert spec in ("gecd,edf->gecf", "gecf,efd->gecd"), spec
+            w_qt = quantize_expert_bank(w)
+            he = jnp.moveaxis(h.astype(jnp.float32), 1, 0)  # (E,G,C,K)
+            e_, g_, c_, k_ = he.shape
+            he = he.reshape(e_, g_ * c_, k_)
+            cfg = approx_cfg
+            if isinstance(cfg, jax.Array) and cfg.ndim == 1:
+                # (g,) per-neuron-group vector shared by every expert
+                cfg = jnp.broadcast_to(cfg[None, :],
+                                       (e_,) + cfg.shape)
+            if grouped:
+                from repro.kernels.approx_mac.ops import \
+                    approx_dense_grouped_pallas
+                y = approx_dense_grouped_pallas(
+                    he, w_qt, config=cfg, interpret=interpret,
+                    compute_dtype=jnp.float32)
+            else:
+                # per-expert lax.map A/B path — one kernel call per
+                # expert on the same bank + shared activation scale
+                from repro.kernels.approx_mac.ops import approx_mac
+                h_qt = quantize(he)
+                cfg_e = jnp.broadcast_to(
+                    jnp.asarray(cfg, jnp.int32),
+                    (e_,) + jnp.shape(cfg)[1:])
+                acc = jax.lax.map(
+                    lambda ew: approx_mac(ew[0], ew[1], ew[2],
+                                          interpret=interpret),
+                    (h_qt.values, w_qt.values, cfg_e))
+                # combined scale rounded once — the shared rescale
+                # convention (see core.approx_matmul.approx_dense)
+                y = acc.astype(jnp.float32) \
+                    * (h_qt.scale * w_qt.scale[:, None, :])
+            return jnp.moveaxis(y.reshape(e_, g_, c_, -1), 0, 1
+                                ).astype(h.dtype)       # (G,E,C,N)
+        assert not vector_cfg, \
+            "per-block/per-expert config vectors require backend='pallas'"
+        h_qt = quantize(h.astype(jnp.float32))
+        # the SAME bank quantization as the pallas path (per-expert
+        # per-output-channel scales), so float and pre-quantized
+        # parameters are bit-identical on this backend too
+        w_qt = quantize_expert_bank(w)
+        w_scale = w_qt.scale[None, :, None, :]          # (1,E,1,N)
+        d_a, d_b, gate_th, rtn = resolve_operand_params(approx_cfg)
+        hq = truncate_operand_lsb(h_qt.values, d_a, gate_th, rtn)
+        wq = truncate_operand_lsb(w_qt.values, d_b, gate_th, rtn)
+        acc = jnp.einsum(spec, hq.astype(jnp.int32),
+                         wq.astype(jnp.int32))
+        return (acc.astype(jnp.float32) * (h_qt.scale * w_scale)
+                ).astype(h.dtype)
+    if isinstance(w, QTensor):
+        w = w.dequantize()
+    return jnp.einsum(spec, h, w.astype(h.dtype))
+
+
+def _bank_gemm(h, w: LayerBank, approx_cfg, rows, interpret: bool):
+    """(G, E, C, K) @ layer `w.layer` of the stacked bank -> (G, E, C, N)
+    in h's dtype, through the in-place bank kernel."""
+    from repro.kernels.approx_mac.ops import approx_dense_bank_pallas
+    g, e, c, k = h.shape
+    he = jnp.moveaxis(h, 1, 0).reshape(e, g * c, k)
+    cfg = approx_cfg
+    if isinstance(cfg, jax.Array) and cfg.ndim == 1:
+        # (g,) per-neuron-group vector shared by every expert
+        cfg = jnp.broadcast_to(cfg[None, :], (e,) + cfg.shape)
+    y = approx_dense_bank_pallas(he, w.bank, w.layer, w.scale, cfg, rows,
+                                 interpret=interpret)
+    return jnp.moveaxis(y.reshape(e, g, c, -1), 0, 1)
 
 
 def moe_dense_oracle(x, params, *, n_experts: int, top_k: int,

@@ -36,7 +36,7 @@ import numpy as np
 from repro.core.quantization import QTensor, expand_left, quantize
 from .attention import chunked_attention, decode_attention
 from .layers import ACT, dense, dense_init, embed_init, layernorm, rmsnorm, softcap
-from .moe import moe_ffn
+from .moe import LayerBank, moe_ffn, repeated
 from .recurrent import (mlstm_block_init, mlstm_parallel, mlstm_step,
                         recurrent_block, recurrent_block_init,
                         slstm_block_init, slstm_scan, slstm_step)
@@ -739,6 +739,62 @@ def _layer_cfg_plan(blocks, approx_cfg, npat: int):
     return n_groups, acfg_scan, acfg_rest
 
 
+def _is_qtensor(node) -> bool:
+    return isinstance(node, QTensor)
+
+
+def _hoist_banks(stack):
+    """(the stack with each stacked expert bank's (L, E, K, N) int8
+    values replaced by None, those values in tree order)."""
+    leaves, treedef = jax.tree.flatten(stack, is_leaf=_is_qtensor)
+    banks = []
+    for i, leaf in enumerate(leaves):
+        if _is_qtensor(leaf) and leaf.values.ndim == 4 \
+                and jnp.ndim(leaf.scale) == 3:
+            banks.append(leaf.values)
+            leaves[i] = QTensor(None, leaf.scale, leaf.axis)
+    return jax.tree.unflatten(treedef, leaves), banks
+
+
+def _bind_banks(gp, banks, layer):
+    """One group's params with each hoisted bank as a LayerBank."""
+    leaves, treedef = jax.tree.flatten(gp, is_leaf=_is_qtensor)
+    it = iter(banks)
+    leaves = [LayerBank(next(it), q.scale, layer, q.axis)
+              if _is_qtensor(q) and q.values is None else q for q in leaves]
+    return jax.tree.unflatten(treedef, leaves)
+
+
+def _scan_layer_groups(fn, x, stack, *xs, scan_layers: bool = True):
+    """``fn(x, (gp, *xs_g)) -> (x, y)`` over the layer groups of a
+    stacked "scan" tree, through lax.scan (or an unrolled loop when not
+    cfg.scan_layers); returns (x, the y's stacked).  Every scanned site
+    goes through here.
+
+    Stacked expert banks stay out of the scanned operands: the body gets
+    each as a moe.LayerBank, the whole (L, E, K, N) bank plus the group
+    index (the per-expert scales are scanned as usual), so an expert
+    GEMM can read its layer's tiles in place rather than from a
+    per-layer copy of the slice.  A stack without banks scans exactly
+    as before."""
+    hoisted, banks = _hoist_banks(stack)
+    n = jax.tree.leaves(stack)[0].shape[0]
+    if scan_layers:
+        with repeated(n):
+            if not banks:
+                return jax.lax.scan(fn, x, (stack,) + xs)
+            return jax.lax.scan(
+                lambda x, t: fn(x, (_bind_banks(t[1], banks, t[0]),)
+                                + t[2:]),
+                x, (jnp.arange(n), hoisted) + xs)
+    ys = []
+    for g in range(n):
+        gp = _bind_banks(jax.tree.map(lambda a: a[g], hoisted), banks, g)
+        x, y = fn(x, (gp,) + jax.tree.map(lambda a: a[g], xs))
+        ys.append(y)
+    return x, jax.tree.map(lambda *a: jnp.stack(a), *ys)
+
+
 def _run_blocks(blocks, x, cfg, *, positions, approx_cfg=0, causal=True,
                 enc_out=None, pattern=None):
     pattern = pattern or cfg.pattern
@@ -750,8 +806,8 @@ def _run_blocks(blocks, x, cfg, *, positions, approx_cfg=0, causal=True,
     # runtime config), or a (n_layers,) vector (per-layer runtime
     # configs, e.g. a DynamicPowerController allocation).  The vector's
     # scanned prefix rides through lax.scan alongside the layer params.
-    n_groups, acfg_scan, acfg_rest = _layer_cfg_plan(blocks, approx_cfg,
-                                                     npat)
+    _, acfg_scan, acfg_rest = _layer_cfg_plan(blocks, approx_cfg,
+                                              npat)
 
     def group_body(x, gp, ac):
         for j, kind in enumerate(pattern):
@@ -768,15 +824,9 @@ def _run_blocks(blocks, x, cfg, *, positions, approx_cfg=0, causal=True,
                       if cfg.remat_policy == "dots"
                       else jax.checkpoint_policies.nothing_saveable)
             body = jax.checkpoint(group_body, policy=policy)
-        if cfg.scan_layers:
-            x, _ = jax.lax.scan(
-                lambda c, t: (body(c, t[0], t[1]), None),
-                x, (blocks["scan"], acfg_scan))
-        else:
-            for g in range(n_groups):
-                gp = jax.tree.map(lambda a: a[g], blocks["scan"])
-                x = body(x, gp,
-                         acfg_scan[g] if acfg_scan is not None else None)
+        x, _ = _scan_layer_groups(lambda c, t: (body(c, *t), None), x,
+                                  blocks["scan"], acfg_scan,
+                                  scan_layers=cfg.scan_layers)
     r = 0
     while f"rest{r}" in blocks:
         # rest layers follow n_groups*npat scanned layers, so their kind
@@ -1130,8 +1180,8 @@ def decode_step(params, cfg: ModelConfig, cache, token, *,
     new_cache: Params = {"pos": pos + 1}
 
     npat = len(cfg.pattern)
-    n_groups, acfg_scan, acfg_rest = _layer_cfg_plan(params["blocks"],
-                                                     approx_cfg, npat)
+    _, acfg_scan, acfg_rest = _layer_cfg_plan(params["blocks"],
+                                              approx_cfg, npat)
 
     if "scan" in params["blocks"]:
         def scan_fn(x, gp_cl_ac):
@@ -1144,21 +1194,9 @@ def decode_step(params, cfg: ModelConfig, cache, token, *,
                     approx_cfg=approx_cfg if ac is None else ac[j])
                 ncl[f"b{j}"] = c
             return x, ncl
-        if cfg.scan_layers:
-            x, new_scan = jax.lax.scan(scan_fn, x, (params["blocks"]["scan"],
-                                                    cache["scan"],
-                                                    acfg_scan))
-        else:
-            outs = []
-            for g in range(n_groups):
-                gp_cl = jax.tree.map(lambda a: a[g],
-                                     (params["blocks"]["scan"],
-                                      cache["scan"]))
-                ac = acfg_scan[g] if acfg_scan is not None else None
-                x, ncl = scan_fn(x, gp_cl + (ac,))
-                outs.append(ncl)
-            new_scan = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-        new_cache["scan"] = new_scan
+        x, new_cache["scan"] = _scan_layer_groups(
+            scan_fn, x, params["blocks"]["scan"], cache["scan"], acfg_scan,
+            scan_layers=cfg.scan_layers)
     r = 0
     while f"rest{r}" in params["blocks"]:
         kind = cfg.pattern[r % len(cfg.pattern)]
@@ -1268,8 +1306,8 @@ def decode_verify(params, cfg: ModelConfig, cache, tokens, pos, *,
                          )[None].astype(x.dtype)
     new_cache: Params = {"pos": jnp.asarray(pos) + W}
     npat = len(cfg.pattern)
-    n_groups, acfg_scan, acfg_rest = _layer_cfg_plan(params["blocks"],
-                                                     approx_cfg, npat)
+    _, acfg_scan, acfg_rest = _layer_cfg_plan(params["blocks"],
+                                              approx_cfg, npat)
     if "scan" in params["blocks"]:
         def scan_fn(x, gp_cl_ac):
             gp, cl, ac = gp_cl_ac
@@ -1280,21 +1318,9 @@ def decode_verify(params, cfg: ModelConfig, cache, tokens, pos, *,
                     approx_cfg=approx_cfg if ac is None else ac[j])
                 ncl[f"b{j}"] = c
             return x, ncl
-        if cfg.scan_layers:
-            x, new_scan = jax.lax.scan(scan_fn, x, (params["blocks"]["scan"],
-                                                    cache["scan"],
-                                                    acfg_scan))
-        else:
-            outs = []
-            for g in range(n_groups):
-                gp_cl = jax.tree.map(lambda a: a[g],
-                                     (params["blocks"]["scan"],
-                                      cache["scan"]))
-                ac = acfg_scan[g] if acfg_scan is not None else None
-                x, ncl = scan_fn(x, gp_cl + (ac,))
-                outs.append(ncl)
-            new_scan = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-        new_cache["scan"] = new_scan
+        x, new_cache["scan"] = _scan_layer_groups(
+            scan_fn, x, params["blocks"]["scan"], cache["scan"], acfg_scan,
+            scan_layers=cfg.scan_layers)
     r = 0
     while f"rest{r}" in params["blocks"]:
         x, c = _verify_block(params["blocks"][f"rest{r}"], x,
@@ -1353,8 +1379,8 @@ def prefill(params, cfg: ModelConfig, tokens, *, vision_embeds=None,
     positions = jnp.arange(x.shape[1])[None]
 
     npat = len(cfg.pattern)
-    n_groups, acfg_scan, acfg_rest = _layer_cfg_plan(params["blocks"],
-                                                     approx_cfg, npat)
+    _, acfg_scan, acfg_rest = _layer_cfg_plan(params["blocks"],
+                                              approx_cfg, npat)
 
     def fill_block(p, kind, x, cl, approx_cfg=approx_cfg):
         from .layers import apply_rope
@@ -1444,21 +1470,9 @@ def prefill(params, cfg: ModelConfig, tokens, *, vision_embeds=None,
                     approx_cfg=approx_cfg if ac is None else ac[j])
                 ncl[f"b{j}"] = c
             return x, ncl
-        if cfg.scan_layers:
-            x, new_scan = jax.lax.scan(scan_fn, x, (params["blocks"]["scan"],
-                                                    cache["scan"],
-                                                    acfg_scan))
-        else:
-            outs = []
-            for g in range(n_groups):
-                gp_cl = jax.tree.map(lambda a: a[g],
-                                     (params["blocks"]["scan"],
-                                      cache["scan"]))
-                ac = acfg_scan[g] if acfg_scan is not None else None
-                x, ncl = scan_fn(x, gp_cl + (ac,))
-                outs.append(ncl)
-            new_scan = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-        new_cache["scan"] = new_scan
+        x, new_cache["scan"] = _scan_layer_groups(
+            scan_fn, x, params["blocks"]["scan"], cache["scan"], acfg_scan,
+            scan_layers=cfg.scan_layers)
     r = 0
     while f"rest{r}" in params["blocks"]:
         kind = cfg.pattern[r % len(cfg.pattern)]
@@ -1616,8 +1630,8 @@ def paged_decode_step(params, cfg: ModelConfig, cache, token, *,
                          )[:, None].astype(x.dtype)
     new_cache: Params = {}
     npat = len(cfg.pattern)
-    n_groups, acfg_scan, acfg_rest = _layer_cfg_plan(params["blocks"],
-                                                     approx_cfg, npat)
+    _, acfg_scan, acfg_rest = _layer_cfg_plan(params["blocks"],
+                                              approx_cfg, npat)
 
     if "scan" in params["blocks"]:
         def scan_fn(x, gp_cl_ac):
@@ -1631,21 +1645,9 @@ def paged_decode_step(params, cfg: ModelConfig, cache, token, *,
                     backend=backend)
                 ncl[f"b{j}"] = c
             return x, ncl
-        if cfg.scan_layers:
-            x, new_scan = jax.lax.scan(scan_fn, x, (params["blocks"]["scan"],
-                                                    cache["scan"],
-                                                    acfg_scan))
-        else:
-            outs = []
-            for g in range(n_groups):
-                gp_cl = jax.tree.map(lambda a: a[g],
-                                     (params["blocks"]["scan"],
-                                      cache["scan"]))
-                ac = acfg_scan[g] if acfg_scan is not None else None
-                x, ncl = scan_fn(x, gp_cl + (ac,))
-                outs.append(ncl)
-            new_scan = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-        new_cache["scan"] = new_scan
+        x, new_cache["scan"] = _scan_layer_groups(
+            scan_fn, x, params["blocks"]["scan"], cache["scan"], acfg_scan,
+            scan_layers=cfg.scan_layers)
     r = 0
     while f"rest{r}" in params["blocks"]:
         x, c = _paged_attn_block(
@@ -1739,8 +1741,8 @@ def paged_prefill_chunk(params, cfg: ModelConfig, cache, tokens, *,
 
     new_cache: Params = {}
     npat = len(cfg.pattern)
-    n_groups, acfg_scan, acfg_rest = _layer_cfg_plan(params["blocks"],
-                                                     approx_cfg, npat)
+    _, acfg_scan, acfg_rest = _layer_cfg_plan(params["blocks"],
+                                              approx_cfg, npat)
     if "scan" in params["blocks"]:
         def scan_fn(x, gp_cl_ac):
             gp, cl, ac = gp_cl_ac
@@ -1750,21 +1752,9 @@ def paged_prefill_chunk(params, cfg: ModelConfig, cache, tokens, *,
                                   approx_cfg if ac is None else ac[j])
                 ncl[f"b{j}"] = c
             return x, ncl
-        if cfg.scan_layers:
-            x, new_scan = jax.lax.scan(scan_fn, x, (params["blocks"]["scan"],
-                                                    cache["scan"],
-                                                    acfg_scan))
-        else:
-            outs = []
-            for g in range(n_groups):
-                gp_cl = jax.tree.map(lambda a: a[g],
-                                     (params["blocks"]["scan"],
-                                      cache["scan"]))
-                ac = acfg_scan[g] if acfg_scan is not None else None
-                x, ncl = scan_fn(x, gp_cl + (ac,))
-                outs.append(ncl)
-            new_scan = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-        new_cache["scan"] = new_scan
+        x, new_cache["scan"] = _scan_layer_groups(
+            scan_fn, x, params["blocks"]["scan"], cache["scan"], acfg_scan,
+            scan_layers=cfg.scan_layers)
     r = 0
     while f"rest{r}" in params["blocks"]:
         x, c = fill_chunk(params["blocks"][f"rest{r}"], x,

@@ -135,6 +135,7 @@ and ``group`` (index into ``cfg_groups``), in that order.
 from __future__ import annotations
 
 import contextlib
+import functools
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -150,6 +151,7 @@ from repro.core.controller import step_down_config
 from repro.core.power_model import (ENERGY_PER_MAC_PJ, MAC_SAVING_FRAC,
                                     energy_per_token_pj, error_rank)
 from repro.dist.sharding import activate as _activate, lsc_tree
+from repro.nn import moe
 from repro.nn import transformer as T
 from .paged_cache import ZERO_BLOCK, PagedCacheConfig, PageAllocator
 from .sampling import sample
@@ -167,6 +169,21 @@ def _serving_jit(fn):
     a TPU v5e that alone made the xla and pallas backends' greedy tokens
     part ways at the first token; without it they are bit-identical."""
     return jax.jit(fn, compiler_options={"xla_allow_excess_precision": False})
+
+
+def _count_expert_gemms(paths: dict, platform: str, fn, name=None):
+    """`fn`, recording in ``paths[name]`` when it is traced how many
+    expert GEMMs a call runs on each path once lowered for `platform`
+    (moe.count_expert_gemms)."""
+    name = name or fn.__name__
+
+    @functools.wraps(fn)
+    def traced(*args):
+        with moe.count_expert_gemms(platform) as tally:
+            out = fn(*args)
+        paths[name] = dict(tally)
+        return out
+    return traced
 
 
 class _SpecAbort(RuntimeError):
@@ -555,6 +572,13 @@ class Engine:
 
         cfg_ = cfg
         cache_spec_ = self.cache_spec
+        # expert GEMMs per call of each model executable, by path
+        # ("bank_kernel", "xla_einsum", ...), tallied when it is traced
+        self.expert_gemm_paths: dict[str, dict[str, int]] = {}
+        platform = (mapping.mesh.devices.flat[0].platform
+                    if mapping is not None else jax.devices()[0].platform)
+        counted = functools.partial(_count_expert_gemms,
+                                    self.expert_gemm_paths, platform)
 
         # approx_cfg is a TRACED (n_layers,) int32 argument: retuning the
         # engine or mixing request configs never retraces (PR 1).  The
@@ -566,6 +590,7 @@ class Engine:
             backend_ = paged.attn_backend
 
             @_serving_jit
+            @counted
             def _decode(params, cache, token, acfg):
                 return T.paged_decode_step(params, cfg_, cache, token,
                                            approx_cfg=acfg,
@@ -579,12 +604,14 @@ class Engine:
             # the pool on the host) and the mid-prompt chunk step
             # (slot/start/count as traced scalars)
             @_serving_jit
+            @counted
             def _prefill(params, tokens, acfg, true_len):
                 return T.prefill(params, cfg_, tokens,
                                  max_len=paged.prefill_chunk,
                                  approx_cfg=acfg, true_len=true_len)
 
             @_serving_jit
+            @counted
             def _prefill_chunk(params, cache, tokens, slot, start, count,
                                acfg):
                 return T.paged_prefill_chunk(
@@ -595,6 +622,7 @@ class Engine:
             self._prefill_chunk = _prefill_chunk
         else:
             @_serving_jit
+            @counted
             def _decode(params, cache, token, acfg):
                 cache = lsc_tree(cache, cache_spec_)
                 logits, new_cache = T.decode_step(params, cfg_, cache,
@@ -608,11 +636,13 @@ class Engine:
                 # along as a traced scalar (satellite: kills the
                 # per-prompt-length retrace)
                 @_serving_jit
+                @counted
                 def _prefill(params, tokens, acfg, true_len):
                     return T.prefill(params, cfg_, tokens, max_len=max_len,
                                      approx_cfg=acfg, true_len=true_len)
             else:
                 @_serving_jit
+                @counted
                 def _prefill(params, tokens, acfg):
                     return T.prefill(params, cfg_, tokens, max_len=max_len,
                                      approx_cfg=acfg)
@@ -640,10 +670,10 @@ class Engine:
                 # shape speculation adds — k and draft_cfg are host
                 # loop count / traced data (zero retraces across the
                 # whole (k, draft-cfg) sweep)
-                self._verify = _serving_jit(
+                self._verify = _serving_jit(counted(
                     lambda params, cache, tokens, pos, acfg:
                     T.decode_verify(params, cfg_, cache, tokens, pos,
-                                    approx_cfg=acfg))
+                                    approx_cfg=acfg), "_verify"))
 
         # online power-budget scheduler (serve/scheduler.py): hooks into
         # every tick AFTER the jitted functions exist — its shadow

@@ -11,7 +11,9 @@ The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process may load the TPU library, and the
 suite runs under several xdist workers.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +28,8 @@ from repro.kernels.approx_mac.ops import (approx_dense_grouped_pallas,
                                           approx_dense_pallas)
 from repro.kernels.flash_attention.paged_attention import \
     paged_decode_attention
+from repro.nn import moe
+from repro.nn import transformer as T
 
 QWEN = get_config("qwen2.5-3b")
 OLMOE = get_config("olmoe-1b-7b")
@@ -121,3 +125,61 @@ def test_truncation_stays_int8(config):
         vin = np.asarray(v, np.int32)
         assert np.all(np.sign(wide) * np.sign(vin) >= 0)
         assert np.abs(wide - vin).max() <= (1 << depth) - 1
+
+
+def _paged_decode_hlo(cfg, one_chip, rows=16, max_len=896, block=16):
+    """The engine's paged ``_decode`` for `cfg` at the benchmark's decode
+    geometry, compiled for one chip: (HLO text, expert GEMM paths)."""
+    def s(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = jax.eval_shape(lambda k: T.init_serving_lm(k, cfg)[0],
+                            jax.random.PRNGKey(0))
+    cache = dict(jax.eval_shape(lambda: T.init_paged_cache(
+        cfg, rows * max_len // block + 2, block)[0]))
+    cache["tables"] = jax.ShapeDtypeStruct((rows, max_len // block),
+                                           jnp.int32)
+    cache["seq_lens"] = jax.ShapeDtypeStruct((rows,), jnp.int32)
+    cache["active"] = jax.ShapeDtypeStruct((rows,), jnp.bool_)
+
+    def _decode(params, cache, token, acfg):
+        return T.paged_decode_step(params, cfg, cache, token,
+                                   approx_cfg=acfg)
+
+    with moe.count_expert_gemms("tpu") as tally:
+        lowered = jax.jit(_decode).lower(
+            s(params), s(cache), s(jax.ShapeDtypeStruct((rows, 1), jnp.int32)),
+            s(jax.ShapeDtypeStruct((cfg.n_layers,), jnp.int32)))
+    return lowered.compile().as_text(), dict(tally)
+
+
+def test_olmoe_decode_reads_expert_banks_in_place(one_chip):
+    """OLMoE's decode (two layers) runs each expert GEMM as the bank
+    kernel on the whole stacked bank: no bank-shaped copy, slice or
+    truncation pass is left in the program, and the kernel's instruction
+    carries ``approx_mac`` (the benchmark counts GEMM time by it)."""
+    text, paths = _paged_decode_hlo(dataclasses.replace(OLMOE, n_layers=2),
+                                    one_chip)
+    assert paths == {"bank_kernel": 6}
+    bank = re.compile(r"^s8\[(\d+,)?64,(1024,2048|2048,1024)\]")
+    kernels = []
+    for line in text.splitlines():
+        head, _, rest = line.strip().partition(" = ")
+        opcode = re.search(r"\s([a-z][\w\-]*)\(", rest)
+        if bank.match(rest):
+            # the bank only enters as a parameter and reaches the loop
+            # body as a tuple element, the kernel's own operand
+            assert opcode.group(1) in ("parameter", "get-tuple-element"), \
+                line[:200]
+        if 'custom_call_target="tpu_custom_call"' in rest:
+            kernels.append(head)
+    assert len(kernels) == 3 and all("approx_mac" in k for k in kernels), \
+        kernels
+
+
+def test_qwen_decode_runs_no_kernel(one_chip):
+    """A dense model's decode keeps every GEMM on the XLA path."""
+    text, paths = _paged_decode_hlo(dataclasses.replace(QWEN, n_layers=2),
+                                    one_chip)
+    assert paths == {} and "tpu_custom_call" not in text

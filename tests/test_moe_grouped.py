@@ -16,7 +16,7 @@ import pytest
 
 from repro.core.approx_multiplier import N_CONFIGS
 from repro.core.quantization import quantize
-from repro.kernels.approx_mac.ops import (_approx_grouped_fused_jit,
+from repro.kernels.approx_mac.ops import (_approx_mac_grouped_jit,
                                           approx_dense_grouped_pallas,
                                           approx_mac, collapse_expert_cfg)
 from repro.kernels.approx_mac.ref import approx_mac_grouped_ref
@@ -117,14 +117,14 @@ def test_grouped_op_zero_retrace():
     approx_dense_grouped_pallas(X, BANK, config=jnp.zeros((E,), jnp.int32),
                                 group_rows=jnp.full((E,), M, jnp.int32),
                                 interpret=True)
-    n0 = _approx_grouped_fused_jit._cache_size()
+    n0 = _approx_mac_grouped_jit._cache_size()
     for cfg in range(N_CONFIGS):
         approx_dense_grouped_pallas(X, BANK, config=_t(cfg), interpret=True)
         approx_dense_grouped_pallas(
             X, BANK, config=jnp.asarray([cfg, (cfg + 7) % 32, 3], jnp.int32),
             group_rows=jnp.asarray([M, cfg % M, 7], jnp.int32),
             interpret=True)
-    assert _approx_grouped_fused_jit._cache_size() == n0
+    assert _approx_mac_grouped_jit._cache_size() == n0
 
 
 # --- collapse rule for GEMMs without an expert axis -------------------------

@@ -32,7 +32,8 @@ SPEC_NAMES = {"draft_cfg", "draft_config", "draft_k", "spec_k", "k_draft"}
 # buffers) is bounded-state's job via the ``push`` tick method.
 TELEMETRY_NAMES = {"class_budgets", "class_shares", "budget_share",
                    "spike_score", "spike_level"}
-SCALAR_PREFETCH = {"cfg_ref", "rows_ref", "xscale_ref", "bt_ref", "len_ref"}
+SCALAR_PREFETCH = {"cfg_ref", "rows_ref", "xscale_ref", "bt_ref", "len_ref",
+                   "layer_ref"}
 LAX_HOFS = {"scan", "cond", "while_loop", "fori_loop", "switch", "map",
             "associative_scan"}
 TRACED_DECOS = {"jit", "vmap", "grad", "value_and_grad", "when",
