@@ -63,7 +63,7 @@ def main(argv=None) -> int:
         chosen = correct.sample(win, cell.mix, seed)
         ref = cell.reference()
         control = cell.reference(qmax=cell.ref_mod.QMAX_INT4)
-        vocab = cell.conf["model"]["vocab_size"]
+        vocab = cell.vocab
         rng = generator.rng_for(seed, "sample")
         alt_all, alt_row = [], []
 
@@ -85,7 +85,8 @@ def main(argv=None) -> int:
         cmp["altered_row"] = np.concatenate(alt_row) if alt_row else \
             np.zeros(0)
         ctx = readers.Context(win=win, conf=cell.conf, mix=cell.mix,
-                              peaks=cell.peaks, setup_s=setup)
+                              peaks=cell.peaks, setup_s=setup,
+                              work=cell.work)
         load = {name: bench_run.read_metric(ROOT, name, ctx)
                 for name in ("output_tok_s", "itl_p95_ms")}
         load["ttft_p95_ms"] = 1e3 * readers.p95(readers.ttfts(ctx))

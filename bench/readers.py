@@ -2,14 +2,16 @@
 
 A reader is a module with ``read(ctx) -> float | None``; ``ctx`` is the
 run's ``Context``.  None means the run holds nothing to read, and the
-metric is left out of the result line."""
+metric is left out of the result line.  The model's operations and bytes
+come from its architecture's work class (``bench/work.py`` ``Work``),
+which the cell's reference names."""
 from __future__ import annotations
 
 from dataclasses import dataclass, field
 
 import numpy as np
 
-from bench.work import Shapes
+from bench.work import Work
 
 
 @dataclass
@@ -19,13 +21,14 @@ class Context:
     mix: dict                    # traffic file
     peaks: dict                  # bench.peaks entry of the device
     setup_s: float
+    work: type[Work]             # the cell's reference's WORK
     trace: dict | None = None    # bench.trace.reduce() of the traced run
     traced: dict = field(default_factory=dict)   # engine counters and
     # step indices at the traced window's start and stop
 
     @property
-    def shapes(self) -> Shapes:
-        return Shapes.of(self.conf["model"])
+    def shapes(self) -> Work:
+        return self.work.of(self.conf["model"])
 
     @property
     def max_batch(self) -> int:
@@ -120,7 +123,7 @@ def decode_roofline(ctx) -> float | None:
     least = 0.0
     for rows, _ in decode_rows(ctx, steps):
         compute = (sh.decode_gemm_ops(rows) / pk["int8_ops"]
-                   + rows * 2.0 * sh.vocab * sh.d / pk["bf16_flops"])
-        moved = sh.decode_gemm_bytes(rows) + sh.vocab * sh.d * 2
+                   + sh.decode_head_ops(rows) / pk["bf16_flops"])
+        moved = sh.decode_gemm_bytes(rows) + sh.decode_head_bytes(rows)
         least += max(compute, moved / pk["hbm_bytes_per_s"])
     return 100.0 * least / spent if least else None

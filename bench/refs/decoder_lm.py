@@ -22,7 +22,8 @@ entries per expert and prompt chunk, first come first kept; decode
 rows are dropless.
 
 With ``qmax=7`` the same code is the int4 control: the nearest lower
-precision than the int8 the configurations state.
+precision than the int8 the configurations state.  The work it counts
+is ``bench/work.py`` ``Shapes``.
 """
 from __future__ import annotations
 
@@ -31,6 +32,9 @@ import math
 import jax
 import jax.numpy as jnp
 
+from bench.work import Shapes
+
+WORK = Shapes
 HIGHEST = jax.lax.Precision.HIGHEST
 QMAX_INT8 = 127
 QMAX_INT4 = 7

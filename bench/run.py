@@ -7,8 +7,13 @@ A cell is one entry of ``workloads`` in ``BENCHMARK.json``: a
 configuration (``bench/configs/<config>.json``), a traffic mix
 (``bench/traffic/<traffic>.json``) and the limits of its correctness
 check (``bench/cells/<workload>.json``).  Each metric is read by
-``bench/metrics/<metric>.py``.  Everything is found by name, so a cell,
-a mix or a metric is added by adding files.
+``bench/metrics/<metric>.py``.  A configuration names its architecture's
+plain reference, ``bench/refs/<reference>.py`` (its seeded weights, its
+forward pass and its work class ``WORK``), and the program's layout of
+it, ``bench/adapters/<reference>.py`` (the configuration-file keys it
+maps, ``model_config`` and ``serving_params``).  Everything is found by
+name and loaded by path under the run's root, so a cell, a mix, a
+metric or an architecture is added by adding files.
 
 The run makes the served weights on the device from the seed, builds a
 paged ``Engine`` at the configuration's geometry, warms up every shape
@@ -32,7 +37,6 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -102,13 +106,35 @@ def metrics_for(bench: dict, workload: str, trace: bool) -> list[dict]:
     return [m for m in group if workload in m.get("workloads", [workload])]
 
 
-def read_metric(root: Path, name: str, ctx):
-    path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
+def load_module(root: Path, kind: str, name: str):
+    """``bench/<kind>/<name>.py`` under the run's root, loaded by path."""
+    path = root / "bench" / kind / f"{name}.py"
+    if not path.is_file():
+        raise Refused(f"no {path.relative_to(root)} for {name!r}")
+    spec = importlib.util.spec_from_file_location(f"bench_{kind}_{name}",
                                                   path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    return mod
+
+
+def read_metric(root: Path, name: str, ctx):
+    return load_module(root, "metrics", name).read(ctx)
+
+
+def architecture(root: Path, conf: dict):
+    """(reference, adapter) modules of a configuration's ``reference``;
+    Refused where the adapter is missing or maps not every ``model`` key
+    of the file."""
+    ref = conf["reference"]
+    ref_mod = load_module(root, "refs", ref)
+    adapter = load_module(root, "adapters", ref)
+    unmapped = sorted(set(conf["model"]) - set(adapter.MODEL_KEYS))
+    if unmapped:
+        raise Refused(f"bench/adapters/{ref}.py maps no model key "
+                      f"{', '.join(map(repr, unmapped))} of configuration "
+                      f"{conf['name']!r}")
+    return ref_mod, adapter
 
 
 def check_devices(cell: dict):
@@ -165,16 +191,16 @@ class Cell:
         self.peaks = peaks(self.devs[0].device_kind) if require_tpu else \
             {"bf16_flops": 1.0, "int8_ops": 1.0, "hbm_bytes_per_s": 1.0}
         if require_tpu:
-            from repro.launch.compile_cache import enable_compile_cache
-            enable_compile_cache()
+            program.enable_compile_cache()
             jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
             jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
         self.log = CompileLog()
         jax.monitoring.register_event_duration_secs_listener(self.log)
         jax.monitoring.register_event_listener(self.log.hit)
-        self.cfg = program.model_config(self.conf)
-        self.ref_mod = importlib.import_module(
-            f"bench.refs.{self.conf['reference']}")
+        self.ref_mod, adapter = architecture(root, self.conf)
+        self.work = self.ref_mod.WORK
+        self.cfg = adapter.model_config(self.conf)
+        self.vocab = self.cfg.vocab_size
         model = self.conf["model"]
         key = jax.random.fold_in(jax.random.PRNGKey(args.seed % (2 ** 31)),
                                  args.seed // (2 ** 31))
@@ -182,14 +208,13 @@ class Cell:
         self.weights = jax.jit(
             lambda k: self.ref_mod.make_weights(model, k))(key)
         self._note_memory("weights")
-        params = program.serving_params(self.weights, self.cfg)
+        params = adapter.serving_params(self.weights, self.cfg)
         self.eng = program.make_engine(params, self.cfg, self.conf["serving"],
                                        args.seed, clock=time.perf_counter)
         self._note_memory("engine")
         if patch_engine is not None:
             patch_engine(self.eng)
-        warm_up(self.eng, self.mix, self.conf, args.seed,
-                model["vocab_size"])
+        warm_up(self.eng, self.mix, self.conf, args.seed, self.vocab)
         jax.block_until_ready(self.eng.cache)
         self._note_memory("warm_up")
         self.setup_compiles = (self.log.count, self.log.hits)
@@ -220,8 +245,7 @@ class Cell:
         annotate = (lambda name: jax.profiler.TraceAnnotation(name)) \
             if args.trace else None
         before = self.log.count
-        win = loop.run(eng, self.mix, args.seconds, args.seed,
-                       self.conf["model"]["vocab_size"],
+        win = loop.run(eng, self.mix, args.seconds, args.seed, self.vocab,
                        clock=time.perf_counter, annotate=annotate,
                        tracer=tracer)
         compiles = self.log.count - before
@@ -258,8 +282,8 @@ def run(args, root: Path = ROOT, require_tpu: bool = True,
     win, peak, trace, counters, compiles = cell.measure()
 
     ctx = readers.Context(win=win, conf=cell.conf, mix=cell.mix,
-                          peaks=cell.peaks, setup_s=setup_s, trace=trace,
-                          traced=counters)
+                          peaks=cell.peaks, setup_s=setup_s, work=cell.work,
+                          trace=trace, traced=counters)
     metrics = {}
     for m in metrics_for(cell.bench, args.workload, bool(args.trace)):
         v = read_metric(root, m["name"], ctx)

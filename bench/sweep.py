@@ -42,10 +42,9 @@ def main(argv=None) -> int:
     for i, rate in enumerate(float(r) for r in args.rates.split(",")):
         mix = dict(cell.mix, rate_per_s=rate)
         win = loop.run(cell.eng, mix, args.seconds, args.seed + i,
-                       cell.conf["model"]["vocab_size"],
-                       clock=time.perf_counter)
+                       cell.vocab, clock=time.perf_counter)
         ctx = readers.Context(win=win, conf=cell.conf, mix=mix,
-                              peaks=cell.peaks, setup_s=0.0)
+                              peaks=cell.peaks, setup_s=0.0, work=cell.work)
         waits = [r.admit_s - r.due_s for r in win.attempted()
                  if r.admit_s is not None]
         waiting = sum(1 for r in win.attempted()

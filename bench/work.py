@@ -4,10 +4,45 @@ Counted for the work itself, whichever implementation runs it: a routed
 expert layer does top-k experts' work per token, a decode row attends
 to its own context, padding and empty slots count for nothing.  An
 operation is a multiply or an add (a multiply-accumulate is two).
+
+A reference module names its architecture's work class (``WORK``); the
+metric readers (``bench/readers.py``) call nothing of it but ``Work``'s
+methods.  A work class imports nothing of the program.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import Protocol
+
+
+class Work(Protocol):
+    """What the readers ask of an architecture's work class."""
+
+    @classmethod
+    def of(cls, model: dict) -> "Work":
+        """The counts of the configuration file's ``model`` sizes."""
+
+    def gemm_ops(self) -> float:
+        """int8 operations of one token's layer GEMMs."""
+
+    def attn_ops(self, context: float) -> float:
+        """bf16/f32 operations of one token's attention over `context`
+        keys."""
+
+    def head_ops(self) -> float:
+        """bf16/f32 operations of one token's LM head (and router)."""
+
+    def decode_gemm_ops(self, rows: int) -> float:
+        """int8 operations of a decode call's layer GEMMs over `rows`."""
+
+    def decode_gemm_bytes(self, rows: int) -> float:
+        """Bytes a decode call's layer GEMMs must move for `rows`."""
+
+    def decode_head_ops(self, rows: int) -> float:
+        """bf16 operations of a decode call's LM head over `rows`."""
+
+    def decode_head_bytes(self, rows: int) -> float:
+        """Bytes a decode call's LM head must move for `rows`."""
 
 
 @dataclass(frozen=True)
@@ -93,3 +128,11 @@ class Shapes:
 
     def decode_gemm_ops(self, rows: int) -> float:
         return rows * self.gemm_ops()
+
+    def decode_head_ops(self, rows: int) -> float:
+        """The bf16 LM head over `rows`."""
+        return rows * 2.0 * self.vocab * self.d
+
+    def decode_head_bytes(self, rows: int) -> float:
+        """The bf16 LM head read once, whatever the rows."""
+        return float(self.vocab * self.d * 2)

@@ -1,8 +1,9 @@
 """A copy of the benchmark with cells at the models' ``smoke()`` widths,
 added the way a later change adds one: new configuration, traffic, cell
 and metric files, new entries in ``BENCHMARK.json``, and the new cells
-named in the ``workloads`` of the metrics they report.  No file that is
-there is edited."""
+named in the ``workloads`` of the metrics they report.  One of them is
+of an architecture the benchmark does not have: its reference, work
+class and adapter are new files too.  No file that is there is edited."""
 from __future__ import annotations
 
 import json
@@ -40,6 +41,72 @@ METRIC = '''"""Requests that finished, a count read from the window's records.""
 def read(ctx):
     return float(sum(r.status == "done" for r in ctx.win.attempted()))
 '''
+WORK_METRIC = '''"""int8 operations of one token's layer GEMMs, by the cell's work class."""
+
+
+def read(ctx):
+    return ctx.shapes.gemm_ops()
+'''
+
+# An architecture under a reference name no file of the repo has: the
+# dense decoder, with its configuration file's keys named otherwise.
+RENAMED = {"layers": 2, "width": 64, "heads": 2, "kv_heads": 2,
+           "head_width": 32, "ffn_width": 128, "vocab": 128,
+           "rope_base": 1000000.0, "tied": True, "biased_qkv": True}
+RENAMED_REF = '''"""decoder_lm's arithmetic over a configuration file whose model keys
+are named otherwise, with a work class of its own."""
+from bench.refs import decoder_lm
+from bench.work import Shapes
+
+QMAX_INT8, QMAX_INT4 = decoder_lm.QMAX_INT8, decoder_lm.QMAX_INT4
+NAMES = {"layers": "num_hidden_layers", "width": "hidden_size",
+         "heads": "num_attention_heads", "kv_heads": "num_key_value_heads",
+         "head_width": "head_dim", "ffn_width": "intermediate_size",
+         "vocab": "vocab_size", "rope_base": "rope_theta",
+         "tied": "tie_word_embeddings", "biased_qkv": "attention_bias"}
+
+
+def plain(model):
+    return {NAMES[k]: v for k, v in model.items()}
+
+
+def make_weights(model, key):
+    return decoder_lm.make_weights(plain(model), key)
+
+
+def forward_logits(w, model, **kw):
+    return decoder_lm.forward_logits(w, plain(model), **kw)
+
+
+class Work(Shapes):
+    @classmethod
+    def of(cls, model):
+        m = plain(model)
+        return cls(m["num_hidden_layers"], m["hidden_size"],
+                   m["num_attention_heads"], m["num_key_value_heads"],
+                   m["head_dim"], m["intermediate_size"], m["vocab_size"])
+
+
+WORK = Work
+'''
+RENAMED_ADAPTER = '''"""The program's layout of the renamed reference: decoder_lm's, with
+the configuration file's keys mapped by their own names."""
+from bench import program
+from bench.adapters import decoder_lm
+
+MODEL_KEYS = {"layers": "n_layers", "width": "d_model", "heads": "n_heads",
+              "kv_heads": "n_kv_heads", "head_width": "head_dim",
+              "ffn_width": "d_ff", "vocab": "vocab_size",
+              "rope_base": "rope_theta", "tied": "tie_embeddings",
+              "biased_qkv": "qkv_bias"}
+
+
+def model_config(conf):
+    return program.model_config(conf, MODEL_KEYS)
+
+
+serving_params = decoder_lm.serving_params
+'''
 
 
 # the smoke mixes check every finished request: a fault that hits some
@@ -50,7 +117,10 @@ def build(dest: Path, limits: dict | None = None,
           configs: tuple = (0, 31)) -> Path:
     """Copy the benchmark to `dest` and add the smoke cells
     ``smoke-qwen.chat`` and ``smoke-olmoe.decode``, their files and a
-    metric ``smoke.done_requests`` read in both."""
+    metric ``smoke.done_requests`` read in both, and the cell
+    ``smoke-renamed.chat`` of the architecture ``renamed_lm`` (chat
+    traffic, the dense decoder's smoke widths under other key names)
+    with a metric ``smoke.gemm_ops`` read in it alone."""
     dest = Path(dest)
     shutil.copytree(REPO / "bench", dest / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
@@ -59,14 +129,14 @@ def build(dest: Path, limits: dict | None = None,
     bench = json.loads((dest / "BENCHMARK.json").read_text())
     base = json.loads((REPO / "bench" / "configs" / "olmoe-1b-7b.json")
                       .read_text())
-    for name, model, extra, over in (
-            ("smoke-qwen", QWEN, {}, {}),
-            ("smoke-olmoe", OLMOE, {"moe_capacity_factor": 1.25},
-             {"n_experts": 64, "top_k": 8})):
-        conf = {"name": name, "registry": name.replace("smoke-", "")
-                .replace("qwen", "qwen2.5-3b").replace("olmoe", "olmoe-1b-7b"),
-                "reference": "decoder_lm", "smoke": True, "reduced": [],
-                "source": base["source"], "model": model, "overrides": over,
+    for name, registry, reference, model, extra, over in (
+            ("smoke-qwen", "qwen2.5-3b", "decoder_lm", QWEN, {}, {}),
+            ("smoke-olmoe", "olmoe-1b-7b", "decoder_lm", OLMOE,
+             {"moe_capacity_factor": 1.25}, {"n_experts": 64, "top_k": 8}),
+            ("smoke-renamed", "qwen2.5-3b", "renamed_lm", RENAMED, {}, {})):
+        conf = {"name": name, "registry": registry, "reference": reference,
+                "smoke": True, "reduced": [], "source": base["source"],
+                "model": model, "overrides": over,
                 "serving": dict(SERVING, **extra)}
         (dest / "bench" / "configs" / f"{name}.json").write_text(
             json.dumps(conf))
@@ -76,21 +146,30 @@ def build(dest: Path, limits: dict | None = None,
         (dest / "bench" / "traffic" / f"{name}.json").write_text(
             json.dumps(mix))
     (dest / "bench" / "metrics" / "smoke.done_requests.py").write_text(METRIC)
+    (dest / "bench" / "metrics" / "smoke.gemm_ops.py").write_text(WORK_METRIC)
+    (dest / "bench" / "refs" / "renamed_lm.py").write_text(RENAMED_REF)
+    (dest / "bench" / "adapters" / "renamed_lm.py").write_text(
+        RENAMED_ADAPTER)
     cells = {"smoke-qwen.chat": ("smoke-qwen", "smoke-chat"),
-             "smoke-olmoe.decode": ("smoke-olmoe", "smoke-decode")}
+             "smoke-olmoe.decode": ("smoke-olmoe", "smoke-decode"),
+             "smoke-renamed.chat": ("smoke-renamed", "smoke-chat")}
     for cell, (conf, mix) in cells.items():
         bench["workloads"].append({"name": cell, "config": conf,
                                    "traffic": mix, "chips": 1,
                                    "why": "smoke widths on the CPU"})
         (dest / "bench" / "cells" / f"{cell}.json").write_text(json.dumps(
             {"limits": limits or {f"gap_max_cfg{c}": 1e6 for c in configs}}))
-    twin = {"qwen2.5-3b.chat": "smoke-qwen.chat",
-            "olmoe-1b-7b.decode": "smoke-olmoe.decode"}
+    twins = {"qwen2.5-3b.chat": ["smoke-qwen.chat", "smoke-renamed.chat"],
+             "olmoe-1b-7b.decode": ["smoke-olmoe.decode"]}
     for m in bench["end_to_end"] + bench["per_layer"]:
         if "workloads" in m:
-            m["workloads"] += [twin[w] for w in m["workloads"] if w in twin]
-    bench["end_to_end"].append({"name": "smoke.done_requests", "unit": "1",
-                                "better": "higher", "bound": 0.25,
-                                "source": "host_clock"})
+            m["workloads"] += [t for w in m["workloads"]
+                               for t in twins.get(w, [])]
+    bench["end_to_end"] += [
+        {"name": "smoke.done_requests", "unit": "1", "better": "higher",
+         "bound": 0.25, "source": "host_clock"},
+        {"name": "smoke.gemm_ops", "unit": "ops", "better": "lower",
+         "bound": 0.25, "source": "host_clock",
+         "workloads": ["smoke-renamed.chat"]}]
     (dest / "BENCHMARK.json").write_text(json.dumps(bench))
     return dest

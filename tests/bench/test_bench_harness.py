@@ -3,11 +3,13 @@
 A configuration, a traffic mix, a cell and a metric added under new names
 in a copy of the benchmark are found by name and run end to end (at the
 models' smoke() widths, on the CPU, past the harness's look for a chip)
-with no edit to any file that was there."""
+with no edit to any file that was there; so is an architecture, as its
+reference, work class and adapter."""
 import argparse
 import filecmp
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -33,7 +35,8 @@ def _unchanged(copy: Path) -> bool:
     return True
 
 
-@pytest.mark.parametrize("cell", ["smoke-qwen.chat", "smoke-olmoe.decode"])
+@pytest.mark.parametrize("cell", ["smoke-qwen.chat", "smoke-olmoe.decode",
+                                  "smoke-renamed.chat"])
 def test_cells_added_as_files_are_found_and_run(tmp_path, cell):
     root = _bench_smoke.build(tmp_path)
     assert _unchanged(root)
@@ -43,13 +46,69 @@ def test_cells_added_as_files_are_found_and_run(tmp_path, cell):
     metrics = result["metrics"]
     wanted = {"setup_s", "smoke.done_requests"} | (
         {"itl_p95_ms"} if cell.endswith("chat")
-        else {"output_tok_s"})
+        else {"output_tok_s"}) | (
+        {"smoke.gemm_ops"} if cell == "smoke-renamed.chat" else set())
     assert set(metrics) == wanted
+    if "smoke.gemm_ops" in metrics:
+        # the renamed architecture's own work class, read through its
+        # own key names: 2 layers of q, k, v, o and a SwiGLU at width 64
+        m = _bench_smoke.RENAMED
+        per_layer = m["width"] * m["head_width"] * 2 * (
+            m["heads"] + m["kv_heads"]) + 3 * m["width"] * m["ffn_width"]
+        assert metrics["smoke.gemm_ops"]["value"] == \
+            2.0 * m["layers"] * per_layer
     assert metrics["smoke.done_requests"]["value"] >= 1
     assert result["attempted"] >= 1 and result["failed"] == 0
     assert result["notes"]["compiles_in_window"] == 0
     assert list(result)[-1] == "checks"
     assert result["correct"] is True
+
+
+def _add_config(root: Path, name: str, conf: dict) -> str:
+    """A cell ``<name>.chat`` in the copy at `root` for the configuration
+    `conf`, under `name`; returns the cell's name."""
+    (root / "bench" / "configs" / f"{name}.json").write_text(
+        json.dumps(dict(conf, name=name)))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["workloads"].append({"name": f"{name}.chat", "config": name,
+                               "traffic": "smoke-chat", "chips": 1,
+                               "why": "refused before any run"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    (root / "bench" / "cells" / f"{name}.chat.json").write_text(
+        json.dumps({"limits": {"gap_max_cfg0": 1e6}}))
+    return f"{name}.chat"
+
+
+@pytest.mark.parametrize("case", ["missing_adapter", "unmapped_key"])
+def test_an_architecture_it_cannot_run_is_refused_by_name(tmp_path, case):
+    root = _bench_smoke.build(tmp_path)
+    conf = json.loads((root / "bench" / "configs" / "smoke-qwen.json")
+                      .read_text())
+    if case == "missing_adapter":
+        shutil.copy(root / "bench" / "refs" / "renamed_lm.py",
+                    root / "bench" / "refs" / "lonely_lm.py")
+        conf = dict(conf, reference="lonely_lm",
+                    model=_bench_smoke.RENAMED)
+        said = "no bench/adapters/lonely_lm.py"
+    else:
+        conf = dict(conf, model=dict(conf["model"], kv_lora_rank=512))
+        said = "bench/adapters/decoder_lm.py maps no model key 'kv_lora_rank'"
+    cell = _add_config(root, f"smoke-{case}", conf)
+    args = argparse.Namespace(workload=cell, seed=7, seconds=1.0, trace=0)
+    with pytest.raises(bench_run.Refused, match=re.escape(said)):
+        bench_run.run(args, root=root, require_tpu=False)
+
+
+def test_only_program_and_adapters_import_the_program():
+    """The benchmark takes the system under test through bench/program.py
+    and bench/adapters/ alone; a reference or a work class never does."""
+    importing = {path.relative_to(REPO).as_posix()
+                 for path in (REPO / "bench").rglob("*.py")
+                 if re.search(r"^\s*(from|import)\s+repro\b",
+                              path.read_text(), re.M)}
+    assert {"bench/program.py", "bench/adapters/decoder_lm.py"} <= importing
+    assert all(path == "bench/program.py"
+               or path.startswith("bench/adapters/") for path in importing)
 
 
 def test_same_seed_sends_the_same_requests_and_seeds_share_sizes():

@@ -17,6 +17,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 import _bench_smoke  # noqa: E402
 from bench import correct, generator, program  # noqa: E402
+from bench import run as bench_run  # noqa: E402
 from bench.refs import decoder_lm  # noqa: E402
 
 
@@ -39,14 +40,14 @@ def test_lone_request_at_config_0_is_the_reference_argmax(tmp_path):
     conf = json.loads((root / "bench" / "configs" / "smoke-qwen.json")
                       .read_text())
     conf["serving"] = dict(conf["serving"], max_batch=1)
-    cfg = program.model_config(conf)
-    w = jax.jit(lambda k: decoder_lm.make_weights(conf["model"], k))(
+    ref_mod, adapter = bench_run.architecture(root, conf)
+    cfg = adapter.model_config(conf)
+    w = jax.jit(lambda k: ref_mod.make_weights(conf["model"], k))(
         jax.random.PRNGKey(5))
-    eng = program.make_engine(program.serving_params(w, cfg), cfg,
+    eng = program.make_engine(adapter.serving_params(w, cfg), cfg,
                               conf["serving"], 5, time.perf_counter)
     rng = np.random.default_rng(5)
-    ref = correct.Reference(decoder_lm, conf, w, (0,), 24,
-                            decoder_lm.QMAX_INT8)
+    ref = correct.Reference(ref_mod, conf, w, (0,), 24, ref_mod.QMAX_INT8)
     for n_prompt in (20, 45):            # one chunk, and two chunks
         spec = generator.Spec(0, 0.0, rng.integers(0, 128, n_prompt,
                                                    dtype=np.int32), 24)
