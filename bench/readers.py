@@ -22,7 +22,8 @@ class Context:
     peaks: dict                  # bench.peaks entry of the device
     setup_s: float
     work: type[Work]             # the cell's reference's WORK
-    trace: dict | None = None    # bench.trace.reduce() of the traced run
+    trace: dict | None = None    # bench.trace.reduce() and
+    # bench.spans.reduce() of the traced run
     traced: dict = field(default_factory=dict)   # engine counters and
     # step indices at the traced window's start and stop
 
@@ -126,4 +127,45 @@ def decode_roofline(ctx) -> float | None:
                    + sh.decode_head_ops(rows) / pk["bf16_flops"])
         moved = sh.decode_gemm_bytes(rows) + sh.decode_head_bytes(rows)
         least += max(compute, moved / pk["hbm_bytes_per_s"])
+    return 100.0 * least / spent if least else None
+
+
+def scope_s(reduced: dict | None, module: str, scope: str) -> float:
+    """Device seconds of `module` inside `scope` (every scope label that
+    starts with it) in a run's ``trace``."""
+    by = (reduced or {}).get("scope_s", {}).get(module, {})
+    return sum(v for k, v in by.items() if k.split("/")[0] == scope)
+
+
+def scope_ms_per_call(reduced: dict | None, module: str,
+                      scope: str) -> float | None:
+    """Device time per call of `module` inside `scope`."""
+    m = (reduced or {}).get("modules", {}).get(module)
+    s = scope_s(reduced, module, scope)
+    return 1e3 * s / m["count"] if m and m["count"] and s else None
+
+
+def idle_ms_per_tick(reduced: dict | None) -> float | None:
+    """Device idle time inside the traced engine ticks, per tick."""
+    idle = (reduced or {}).get("engine_idle")
+    if not idle or not idle["ticks"]:
+        return None
+    return 1e3 * idle["idle_s"] / idle["ticks"]
+
+
+def scope_roofline(ctx, module: str, scope: str, ops_fn, bytes_fn,
+                   peak: float) -> float | None:
+    """Roofline share of `module`'s work inside `scope` over the traced
+    decode calls: per call, the least time of its work (``ops_fn(rows,
+    context)`` operations at `peak` per second, or ``bytes_fn(rows,
+    context)`` bytes at the chip's HBM bandwidth, whichever is longer),
+    summed, over the device time in the scope."""
+    steps = traced_steps(ctx)
+    spent = scope_s(ctx.trace, module, scope)
+    if steps is None or not spent:
+        return None
+    bw = ctx.peaks["hbm_bytes_per_s"]
+    least = sum(max(ops_fn(rows, context) / peak,
+                    bytes_fn(rows, context) / bw)
+                for rows, context in decode_rows(ctx, steps))
     return 100.0 * least / spent if least else None

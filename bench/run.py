@@ -21,7 +21,12 @@ the cell's traffic uses (set-up), then drives ``Engine.submit`` /
 ``Engine.step`` for ``--seconds`` with the mix's requests and follows
 the requests that arrived to completion.  With ``--trace 0`` it reports
 the cell's end-to-end metrics, with ``--trace 1`` its per-layer metrics
-from a profiler trace of part of the window.  After the window it
+from a profiler trace of part of the window, reduced by
+``bench/trace.py`` and ``bench/spans.py`` (device time per executable,
+per GEMM and per model scope, idle time per engine span).  A traced run
+compiles without the persistent compilation cache: JAX keys the cache
+on the module without its op names, so a cached executable would bring
+another build's names, and no scope, into the trace.  After the window it
 checks the served tokens against the plain reference (bench/correct.py)
 and prints every number compared beside its limit, on standard error
 and as the last key of the result: the last line of standard output,
@@ -53,7 +58,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
-TRACE_DIR = ROOT / ".bench_trace"
+TRACE_DIR = ".bench_trace"          # under the run's root
 # the trace covers the window's last seconds: stopping the profiler holds
 # the host for seconds, and at the window's close no arrival is left to
 # send late
@@ -172,6 +177,19 @@ def warm_up(eng, mix: dict, conf: dict, seed: int, vocab: int) -> None:
     eng.run()
 
 
+def reduce_trace(log_dir: Path, n_devices: int) -> dict:
+    """``bench/trace.py``'s and ``bench/spans.py``'s reductions of the
+    profile under `log_dir`, in one dict."""
+    from jax.profiler import ProfileData
+
+    from bench import spans, trace
+    raw = Path(trace.find_xplane(str(log_dir))).read_bytes()
+    data = ProfileData.from_serialized_xspace(raw)
+    out = trace.reduce(data, n_devices=n_devices)
+    out.update(spans.reduce(data, spans.op_paths(raw), n_devices=n_devices))
+    return out
+
+
 class Cell:
     """A cell ready to measure: its files, the device, the seeded weights
     and a warmed-up engine."""
@@ -190,7 +208,9 @@ class Cell:
             else jax.devices()
         self.peaks = peaks(self.devs[0].device_kind) if require_tpu else \
             {"bf16_flops": 1.0, "int8_ops": 1.0, "hbm_bytes_per_s": 1.0}
-        if require_tpu:
+        if args.trace:
+            jax.config.update("jax_enable_compilation_cache", False)
+        elif require_tpu:
             program.enable_compile_cache()
             jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
             jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
@@ -235,9 +255,10 @@ class Cell:
         from bench import loop
         args, eng = self.args, self.eng
         tracer, counters = None, {}
+        trace_dir = self.root / TRACE_DIR
         if args.trace:
-            shutil.rmtree(TRACE_DIR, ignore_errors=True)
-            tracer = loop.Tracer(str(TRACE_DIR),
+            shutil.rmtree(trace_dir, ignore_errors=True)
+            tracer = loop.Tracer(str(trace_dir),
                                  max(0.0, args.seconds - TRACE_SECONDS),
                                  args.seconds)
             tracer.snapshot = lambda tag: counters.update({
@@ -253,12 +274,10 @@ class Cell:
                    for d in self.devs[:self.cell["chips"]])
         trace = None
         if tracer is not None:
-            from bench import trace as trace_mod
             counters.update(start_step=tracer.started_step,
                             stop_step=tracer.stopped_step)
-            trace = trace_mod.reduce_dir(str(TRACE_DIR),
-                                         n_devices=self.cell["chips"])
-            shutil.rmtree(TRACE_DIR, ignore_errors=True)
+            trace = reduce_trace(trace_dir, self.cell["chips"])
+            shutil.rmtree(trace_dir, ignore_errors=True)
         self.eng = None
         del eng
         gc.collect()
@@ -275,7 +294,7 @@ class Cell:
 def run(args, root: Path = ROOT, require_tpu: bool = True,
         patch_engine=None) -> dict:
     """One run of one cell; returns the result object."""
-    from bench import correct, generator, readers
+    from bench import correct, generator, readers, spans
 
     cell = Cell(args, root, require_tpu, patch_engine)
     setup_s = time.perf_counter() - T_START
@@ -324,6 +343,8 @@ def run(args, root: Path = ROOT, require_tpu: bool = True,
                        "sampled_requests": len(chosen),
                        "sampled_tokens": int(cmp["gap"].size),
                        "drain_capped": win.drain_capped}
+    if trace is not None:
+        result["notes"]["spans"] = spans.readings(trace)
     result["checks"] = checks
     return result
 

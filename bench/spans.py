@@ -1,4 +1,3 @@
-#!/usr/bin/env python3
 """What the program's own names say about a profiler trace: device time
 per named scope of the model, and device idle time inside the engine's
 ticks, split by the engine span the host was in.
@@ -22,36 +21,21 @@ ticks, split by the engine span the host was in.
 
 ``reduce`` keeps ``bench/trace.py``'s window (the harness's spans) and
 its treatment of loop containers, so its numbers add to that module's.
-
-    python3 bench/spans.py --workload qwen2.5-3b.chat --seed 1 \\
-        --seconds 51 [--keep <dir>]
-
-runs one traced run of a cell (``bench/run.py --trace 1``), compiling
-without the persistent compilation cache, and prints its result line
-with these readings added under ``"spans"``; ``--keep`` copies the trace
-there.
+``bench/run.py --trace 1`` adds both reductions to the run's ``trace``,
+which the metric readers read (``bench/readers.py``).
 """
 from __future__ import annotations
 
-import argparse
 import bisect
-import json
 import re
-import shutil
-import sys
 from collections import defaultdict
-from pathlib import Path
 
-ROOT = Path(__file__).resolve().parents[1]
-for p in (str(ROOT / "src"), str(ROOT)):
-    if p not in sys.path:
-        sys.path.insert(0, p)
-
-from bench import trace  # noqa: E402
+from bench import trace
+from bench.readers import idle_ms_per_tick, scope_ms_per_call
 
 SCOPES = ("attention", "gemm", "moe", "lm_head")
 UNSCOPED = "unscoped"
-ENGINE = "engine."
+ENGINE = trace.ENGINE
 TICK = "engine.tick"
 TICK_SELF = "engine.tick (self)"
 PROGRAM_ID = re.compile(r"\((\d+)\)$")
@@ -265,34 +249,12 @@ def reduce(data, paths, host_prefix: str = "bench.",
             "engine_idle": engine_idle(data, merged, w0, w1)}
 
 
-def reduce_file(path: str, **kw) -> dict:
-    from jax.profiler import ProfileData
-    raw = Path(path).read_bytes()
-    return reduce(ProfileData.from_serialized_xspace(raw), op_paths(raw),
-                  **kw)
-
-
 # -- readings --------------------------------------------------------------
 
-def scope_ms_per_call(reduced: dict, module: str, scope: str) -> float | None:
-    """Device time per call of `module` inside `scope` (every label that
-    starts with it), from ``bench/trace.py``'s and this module's keys."""
-    mod = reduced.get("modules", {}).get(module)
-    by = reduced.get("scope_s", {}).get(module)
-    if not mod or not mod["count"] or not by:
-        return None
-    s = sum(v for k, v in by.items() if k.split("/")[0] == scope)
-    return 1e3 * s / mod["count"] if s else None
-
-
-def idle_ms_per_tick(reduced: dict) -> float | None:
-    idle = reduced.get("engine_idle")
-    if not idle or not idle["ticks"]:
-        return None
-    return 1e3 * idle["idle_s"] / idle["ticks"]
-
-
 def readings(reduced: dict, top: int = 10) -> dict:
+    """What a traced run's scopes and ticks show at a glance: the scope
+    readers' numbers, the idle time by innermost engine span (largest
+    first) and the device time by scope of every executable."""
     idle = reduced.get("engine_idle") or {"by_span": {}, "ticks": 0}
     return {
         "decode.attention_ms": scope_ms_per_call(reduced, "_decode",
@@ -305,49 +267,3 @@ def readings(reduced: dict, top: int = 10) -> dict:
         "device_by_scope": reduced.get("scope_s", {}).get("_decode", {}),
         "scope_s": reduced.get("scope_s", {}),
     }
-
-
-def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--keep", default=None,
-                    help="directory to copy the trace to")
-    args = ap.parse_args(argv)
-    from bench import run as bench_run
-    seen = {}
-    plain = trace.reduce_dir
-
-    def reduce_dir(log_dir, **kw):
-        path = trace.find_xplane(log_dir)
-        if args.keep:
-            Path(args.keep).mkdir(parents=True, exist_ok=True)
-            shutil.copy(path, Path(args.keep) / Path(path).name)
-        out = plain(log_dir, **kw)
-        out.update(reduce_file(path, n_devices=kw.get("n_devices")))
-        seen.update(out)
-        return out
-
-    trace.reduce_dir = reduce_dir
-    # JAX keys its persistent compilation cache on the module without its
-    # debug info, op names included: an executable cached by a build
-    # without the scopes would bring its own op names into the trace
-    import jax
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        result = bench_run.run(argparse.Namespace(
-            workload=args.workload, seed=args.seed, seconds=args.seconds,
-            trace=1))
-    except bench_run.Refused as e:
-        print(f"bench: {e}", file=sys.stderr)
-        return 2
-    finally:
-        trace.reduce_dir = plain
-    result["spans"] = readings(seen)
-    print(json.dumps(result))
-    return 0
-
-
-if __name__ == "__main__":
-    sys.exit(main())

@@ -2,8 +2,8 @@
 metrics read: the traced window, the union of device-busy intervals,
 device time per executable, the time of GEMM operations inside each
 executable, the operations that took most time, and the device's
-idle gaps, each named by the host span (``jax.profiler.TraceAnnotation``)
-it falls in.
+idle gaps, each named by the innermost host span
+(``jax.profiler.TraceAnnotation``) it falls in.
 
 What a TPU trace holds (TPU v5e, JAX 0.9; seen by hand in a trace of the
 chat cell): event times of every plane share one clock, relative to the
@@ -19,7 +19,8 @@ operations that make its int8 operands (activation quantization, the
 error config's operand truncation, the layer's weight slice) produce
 ``s8`` results, and the Pallas path runs it all as the ``approx_mac``
 kernel.  Those together are a GEMM's device time.  The host plane
-``/host:CPU`` holds the harness's spans, named with the given prefix.
+``/host:CPU`` holds the harness's spans, named with the given prefix,
+and the engine's (``engine.``) inside them.
 """
 from __future__ import annotations
 
@@ -32,6 +33,7 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 MODULE_LINE = "XLA Modules"
 OP_LINE = "XLA Ops"
 CONTAINERS = ("while", "conditional", "call")
+ENGINE = "engine."   # the engine's spans: they name idle gaps, not the window
 
 
 def module_name(event_name: str) -> str:
@@ -107,23 +109,27 @@ def _owner(mods, t):
 def reduce(data, host_prefix: str = "bench.", n_devices: int | None = None,
            top: int = 10) -> dict:
     """Reduce a ``jax.profiler.ProfileData``; times in seconds.  The
-    window runs from the first host span to the end of the last."""
-    devices, spans = [], []
+    window runs from the first host span to the end of the last; an idle
+    gap is named by the innermost host or engine span holding it."""
+    devices, spans, named = [], [], []
     for plane in data.planes:
         m = DEVICE_PLANE.match(plane.name)
         if m:
             devices.append((int(m.group(1)), plane))
         elif plane.name == "/host:CPU":
             for line in plane.lines:
-                spans.extend((ev.start_ns, ev.end_ns, ev.name)
-                             for ev in line.events
-                             if ev.name.startswith(host_prefix))
+                for ev in line.events:
+                    if ev.name.startswith(host_prefix):
+                        spans.append((ev.start_ns, ev.end_ns, ev.name))
+                    if ev.name.startswith((host_prefix, ENGINE)):
+                        named.append((ev.start_ns, ev.end_ns, ev.name))
     devices.sort(key=lambda d: d[0])
     if n_devices is not None:
         devices = devices[:n_devices]
     if not devices:
         raise ValueError("the trace holds no TPU device plane")
     spans.sort()
+    named.sort()
     per_dev = []
     for _, plane in devices:
         lines = {ln.name: list(ln.events) for ln in plane.lines}
@@ -174,7 +180,7 @@ def reduce(data, host_prefix: str = "bench.", n_devices: int | None = None,
     edges = [w0] + [x for iv in merged0 for x in iv] + [w1]
     for a, b in zip(edges[0::2], edges[1::2]):
         if b > a:
-            gaps.append((b - a, _span_at(spans, (a + b) / 2)))
+            gaps.append((b - a, _span_at(named, (a + b) / 2)))
     gaps.sort(key=lambda g: -g[0])
     return {
         "window_s": (w1 - w0) / 1e9,
@@ -199,8 +205,3 @@ def _span_at(spans, t) -> str:
         if e >= t:
             best = name
     return best or "between host spans"
-
-
-def reduce_dir(log_dir: str, **kw) -> dict:
-    from jax.profiler import ProfileData
-    return reduce(ProfileData.from_file(find_xplane(log_dir)), **kw)

@@ -44,6 +44,15 @@ class Work(Protocol):
     def decode_head_bytes(self, rows: int) -> float:
         """Bytes a decode call's LM head must move for `rows`."""
 
+    def decode_attn_ops(self, rows: int, context: float) -> float:
+        """bf16/f32 operations of a decode call's attention over `rows`
+        at mean `context`."""
+
+    def decode_attn_bytes(self, rows: int, context: float) -> float:
+        """Bytes a decode call's attention must move over `rows` at mean
+        `context`: the cache it reads and the new token's entry it
+        writes."""
+
 
 @dataclass(frozen=True)
 class Shapes:
@@ -136,3 +145,12 @@ class Shapes:
     def decode_head_bytes(self, rows: int) -> float:
         """The bf16 LM head read once, whatever the rows."""
         return float(self.vocab * self.d * 2)
+
+    def decode_attn_ops(self, rows: int, context: float) -> float:
+        return rows * self.attn_ops(context)
+
+    def decode_attn_bytes(self, rows: int, context: float) -> float:
+        """Every row's cached K and V read once in bf16, and the new
+        token's K and V written, in every layer."""
+        return float(rows * self.layers * (context + 1) * 2 * self.kv_heads
+                     * self.head_dim * 2)

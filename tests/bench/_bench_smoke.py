@@ -48,6 +48,17 @@ def read(ctx):
     return ctx.shapes.gemm_ops()
 '''
 
+ATTN_METRIC = '''"""Roofline share of the decode calls' attention by the cell's own work
+class (bench.readers)."""
+from bench.readers import scope_roofline
+
+
+def read(ctx):
+    sh = ctx.shapes
+    return scope_roofline(ctx, "_decode", "attention", sh.decode_attn_ops,
+                          sh.decode_attn_bytes, ctx.peaks["bf16_flops"])
+'''
+
 # An architecture under a reference name no file of the repo has: the
 # dense decoder, with its configuration file's keys named otherwise.
 RENAMED = {"layers": 2, "width": 64, "heads": 2, "kv_heads": 2,
@@ -86,6 +97,10 @@ class Work(Shapes):
                    m["num_attention_heads"], m["num_key_value_heads"],
                    m["head_dim"], m["intermediate_size"], m["vocab_size"])
 
+    def decode_attn_bytes(self, rows, context):
+        """Its own count: a cache held in float32, 4 bytes a value."""
+        return 2.0 * Shapes.decode_attn_bytes(self, rows, context)
+
 
 WORK = Work
 '''
@@ -120,7 +135,9 @@ def build(dest: Path, limits: dict | None = None,
     metric ``smoke.done_requests`` read in both, and the cell
     ``smoke-renamed.chat`` of the architecture ``renamed_lm`` (chat
     traffic, the dense decoder's smoke widths under other key names)
-    with a metric ``smoke.gemm_ops`` read in it alone."""
+    with a metric ``smoke.gemm_ops`` read in it alone, and the per-layer
+    metric ``attention_roofline.smoke-chat`` of its work class, which
+    counts attention bytes its own way."""
     dest = Path(dest)
     shutil.copytree(REPO / "bench", dest / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
@@ -147,6 +164,8 @@ def build(dest: Path, limits: dict | None = None,
             json.dumps(mix))
     (dest / "bench" / "metrics" / "smoke.done_requests.py").write_text(METRIC)
     (dest / "bench" / "metrics" / "smoke.gemm_ops.py").write_text(WORK_METRIC)
+    (dest / "bench" / "metrics" / "attention_roofline.smoke-chat.py"
+     ).write_text(ATTN_METRIC)
     (dest / "bench" / "refs" / "renamed_lm.py").write_text(RENAMED_REF)
     (dest / "bench" / "adapters" / "renamed_lm.py").write_text(
         RENAMED_ADAPTER)
@@ -171,5 +190,10 @@ def build(dest: Path, limits: dict | None = None,
         {"name": "smoke.gemm_ops", "unit": "ops", "better": "lower",
          "bound": 0.25, "source": "host_clock",
          "workloads": ["smoke-renamed.chat"]}]
+    bench["per_layer"].append(
+        {"name": "attention_roofline.smoke-chat", "unit": "%",
+         "better": "higher", "source": "device_trace",
+         "layer": "paged attention", "moves": "itl_p95_ms",
+         "workloads": ["smoke-renamed.chat"]})
     (dest / "BENCHMARK.json").write_text(json.dumps(bench))
     return dest

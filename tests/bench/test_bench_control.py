@@ -14,6 +14,11 @@ timed path broken underneath:
 * half of the batch left out (the second half of the active decode rows
   get the first half's logits).
 
+Under a fault every engine tick is held to 0.1 s of wall time (a smoke
+tick computes in a few ms): which requests share a decode call then
+follows from their due times alone, not from how fast the loaded CPU
+runs, so a fault reaches as many rows in every run.
+
 The one-chip cells have no exchange between chips to leave out.  The
 mixes switch the error config live between 0 and 8, as the cells' do,
 and each config's mean gap over the sampled positions is compared.  The
@@ -26,6 +31,7 @@ over 12 seeds at these widths the program read at most 0.0010 and 0.0255
 least 0.0537 and 0.0679 (dense) and 0.1445 and 0.1859 (MoE)."""
 import argparse
 import sys
+import time
 from pathlib import Path
 
 import jax.numpy as jnp
@@ -44,6 +50,7 @@ LIMITS = {"smoke-qwen.chat": {"gap_mean_cfg0": 0.01, "gap_mean_cfg8": 0.05},
           "smoke-olmoe.decode": {"gap_mean_cfg0": 0.04,
                                  "gap_mean_cfg8": 0.11}}
 CELLS = list(LIMITS)
+TICK_S = 0.1
 
 
 @pytest.fixture(scope="module")
@@ -60,13 +67,21 @@ def _args(cell, seed=41):
 
 
 def _wrap_decode(fault):
+    """The engine's decode step broken by `fault`, each tick paced to
+    TICK_S."""
     def patch(eng):
-        decode = eng._decode
+        decode, step = eng._decode, eng.step
 
         def broken(params, cache, token, acfg):
             logits, new = decode(params, cache, token, acfg)
             return fault(logits, new, cache)
-        eng._decode = broken
+
+        def paced():
+            t = time.perf_counter()
+            out = step()
+            time.sleep(max(0.0, TICK_S - (time.perf_counter() - t)))
+            return out
+        eng._decode, eng.step = broken, paced
     return patch
 
 

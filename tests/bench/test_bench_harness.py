@@ -4,7 +4,8 @@ A configuration, a traffic mix, a cell and a metric added under new names
 in a copy of the benchmark are found by name and run end to end (at the
 models' smoke() widths, on the CPU, past the harness's look for a chip)
 with no edit to any file that was there; so is an architecture, as its
-reference, work class and adapter."""
+reference, work class and adapter, and a per-layer metric that reads
+the traced run's scopes through that work class."""
 import argparse
 import filecmp
 import json
@@ -22,7 +23,11 @@ sys.path.insert(0, str(REPO))
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 import _bench_smoke  # noqa: E402
+from bench import readers, spans, trace  # noqa: E402
 from bench import run as bench_run  # noqa: E402
+from bench.peaks import peaks  # noqa: E402
+
+TICK = Path(__file__).resolve().parent / "data" / "chat_engine_tick.xplane.pb"
 
 
 def _unchanged(copy: Path) -> bool:
@@ -62,6 +67,77 @@ def test_cells_added_as_files_are_found_and_run(tmp_path, cell):
     assert result["notes"]["compiles_in_window"] == 0
     assert list(result)[-1] == "checks"
     assert result["correct"] is True
+
+
+@pytest.mark.parametrize("cell", ["smoke-qwen.chat", "smoke-olmoe.decode",
+                                  "smoke-renamed.chat"])
+def test_a_traced_run_reads_every_per_layer_metric_of_its_cell(
+        tmp_path, monkeypatch, cell):
+    """``--trace 1`` drives the window under the profiler and reduces its
+    trace with the scopes and ticks.  The CPU's profile has no device
+    plane: the recorded TPU tick stands in for it.  Every per-layer
+    metric of the cell is read, the added ``attention_roofline.smoke-chat``
+    among them, but ``decode.moe_ms.decode``: the chat tick has no
+    ``moe`` scope, so its reader finds nothing and is left out."""
+    import jax
+    root = _bench_smoke.build(tmp_path)
+    monkeypatch.setattr(trace, "find_xplane", lambda log_dir: str(TICK))
+    cached = jax.config.jax_enable_compilation_cache
+    args = argparse.Namespace(workload=cell, seed=2 ** 33 + 7, seconds=2.0,
+                              trace=1)
+    try:
+        result = bench_run.run(args, root=root, require_tpu=False)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+    assert _unchanged(root)
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    wanted = {m["name"] for m in bench_run.metrics_for(bench, cell, True)}
+    metrics = {k: v["value"] for k, v in result["metrics"].items()}
+    assert set(metrics) == wanted - {"decode.moe_ms.decode"}
+    assert ("attention_roofline.smoke-chat" in metrics) == \
+        (cell == "smoke-renamed.chat")
+    kind = cell.rpartition(".")[2]
+    assert metrics[f"engine.idle_ms_per_tick.{kind}"] == 14.453554
+    if kind == "chat":
+        assert metrics["decode.attention_ms.chat"] == 49.353812
+    assert result["breakdown"]["idle_gaps"][0] == \
+        ["engine.logits_to_host", 0.005482491]
+    assert result["device"]["busy_s"] == 0.124829634
+    assert result["notes"]["spans"]["engine_ticks"] == 1
+    assert list(result)[-1] == "checks" and result["correct"] is True
+    assert not (root / bench_run.TRACE_DIR).exists()
+
+
+def test_a_work_class_counts_attention_its_own_way(tmp_path):
+    """``attention_roofline.smoke-chat`` reads through the cell's work
+    class: over the same window and trace, the renamed architecture's
+    float32 cache count doubles the share the plain decoder's gives:
+    the bytes bound a decode call's attention at these widths."""
+    from types import SimpleNamespace as NS
+
+    from jax.profiler import ProfileData
+    root = _bench_smoke.build(tmp_path)
+    raw = TICK.read_bytes()
+    data = ProfileData.from_serialized_xspace(raw)
+    reduced = trace.reduce(data)
+    reduced.update(spans.reduce(data, spans.op_paths(raw)))
+    win = NS(records=[NS(spec=NS(prompt=[0] * n), token_step=list(steps))
+                      for n, steps in ((30, range(6)), (12, range(2, 9)))])
+    got = {}
+    for name in ("smoke-qwen", "smoke-renamed"):
+        conf = json.loads((root / "bench" / "configs" / f"{name}.json")
+                          .read_text())
+        ref_mod, _ = bench_run.architecture(root, conf)
+        ctx = readers.Context(win=win, conf=conf, mix={},
+                              peaks=peaks("TPU v5 lite"), setup_s=1.0,
+                              work=ref_mod.WORK, trace=reduced,
+                              traced={"start_step": 1, "stop_step": 8})
+        got[name] = bench_run.read_metric(
+            root, "attention_roofline.smoke-chat", ctx)
+    assert _unchanged(root)
+    assert got["smoke-renamed"] == pytest.approx(2 * got["smoke-qwen"],
+                                                 rel=1e-12)
+    assert 0 < got["smoke-qwen"] < got["smoke-renamed"] < 100
 
 
 def _add_config(root: Path, name: str, conf: dict) -> str:

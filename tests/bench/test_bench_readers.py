@@ -1,9 +1,11 @@
 """What the benchmark reads does not drift: the work counts of each
 configuration, found through its reference's ``WORK``, and the readers'
 arithmetic on them (``step_ops``, ``least_compute_s``,
-``decode_roofline`` and the metrics built on them) over one fixed
-synthetic window, pinned to the last bit.  A change to what a metric
-reads fails here."""
+``decode_roofline``, ``scope_roofline`` and the metrics built on them)
+over one fixed synthetic window, pinned to the last bit; the scope and
+tick readers over the same window with the recorded chat tick
+(``data/chat_engine_tick.xplane.pb``) as its trace.  A change to what a
+metric reads fails here."""
 import json
 import sys
 from pathlib import Path
@@ -16,6 +18,7 @@ sys.path.insert(0, str(REPO))
 
 from bench import readers  # noqa: E402
 from bench import run as bench_run  # noqa: E402
+from bench import spans, trace  # noqa: E402
 from bench.peaks import peaks  # noqa: E402
 
 # per configuration: gemm_ops(), attn_ops(1000.5), head_ops(),
@@ -41,9 +44,10 @@ READ = {
 }
 
 
-def _context(config: str) -> readers.Context:
+def _context(config: str, recorded: dict | None = None) -> readers.Context:
     """Four requests over twelve ticks of 70 ms, ten in the window, the
-    trace from tick 3 to 9 with 50 ms of decode GEMMs, on a v5e."""
+    trace from tick 3 to 9 with 50 ms of decode GEMMs, or with the
+    `recorded` trace's reduction, on a v5e."""
     conf = json.loads((REPO / "bench" / "configs" / f"{config}.json")
                       .read_text())
     ref_mod, _ = bench_run.architecture(REPO, conf)
@@ -59,7 +63,7 @@ def _context(config: str) -> readers.Context:
     return readers.Context(win=win, conf=conf, mix={},
                            peaks=peaks("TPU v5 lite"), setup_s=1.0,
                            work=ref_mod.WORK,
-                           trace={"gemm_s": {"_decode": 0.05}},
+                           trace=recorded or {"gemm_s": {"_decode": 0.05}},
                            traced={"start_step": 3, "stop_step": 9})
 
 
@@ -83,3 +87,53 @@ def test_reader_arithmetic_is_pinned(config):
         for name in ("step_mfu.chat", "step_mfu.decode",
                      "gemm_roofline.chat", "gemm_roofline.decode"))
     assert got == READ[config]
+
+
+# per configuration: decode_attn_ops(7, 1000.5), decode_attn_bytes(7, 1000.5)
+ATTN_WORK = {
+    "qwen2.5-3b": (2065416192.0, 258435072.0),
+    "olmoe-1b-7b": (917962752.0, 918880256.0),
+}
+# per configuration, over the window above with the recorded chat tick
+# as its trace: scope_roofline of scope attention, then the metrics
+# decode.attention_ms.chat, attention_roofline.chat,
+# engine.idle_ms_per_tick.chat, decode.moe_ms.decode,
+# attention_roofline.decode, engine.idle_ms_per_tick.decode
+SCOPE_READ = {
+    "qwen2.5-3b": (0.2590097980500651, 49.353812, 0.2590097980500651,
+                   14.453554, None, 0.2590097980500651, 14.453554),
+    "olmoe-1b-7b": (0.9209237264002315, 49.353812, 0.9209237264002315,
+                    14.453554, None, 0.9209237264002315, 14.453554),
+}
+NEW_METRICS = ("decode.attention_ms.chat", "attention_roofline.chat",
+               "engine.idle_ms_per_tick.chat", "decode.moe_ms.decode",
+               "attention_roofline.decode", "engine.idle_ms_per_tick.decode")
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    from jax.profiler import ProfileData
+    raw = (REPO / "tests" / "bench" / "data"
+           / "chat_engine_tick.xplane.pb").read_bytes()
+    data = ProfileData.from_serialized_xspace(raw)
+    out = trace.reduce(data)
+    out.update(spans.reduce(data, spans.op_paths(raw)))
+    return out
+
+
+@pytest.mark.parametrize("config", sorted(ATTN_WORK))
+def test_attention_work_counts_are_pinned(config):
+    sh = _context(config).shapes
+    assert (sh.decode_attn_ops(7, 1000.5),
+            sh.decode_attn_bytes(7, 1000.5)) == ATTN_WORK[config]
+
+
+@pytest.mark.parametrize("config", sorted(SCOPE_READ))
+def test_scope_readers_are_pinned_on_the_recorded_trace(config, recorded):
+    ctx = _context(config, recorded)
+    sh = ctx.shapes
+    got = (readers.scope_roofline(ctx, "_decode", "attention",
+                                  sh.decode_attn_ops, sh.decode_attn_bytes,
+                                  ctx.peaks["bf16_flops"]),) + tuple(
+        bench_run.read_metric(REPO, name, ctx) for name in NEW_METRICS)
+    assert got == SCOPE_READ[config]

@@ -135,6 +135,26 @@ def test_engine_idle_matches_a_brute_force_grid(data, reduced):
     assert got["idle_s"] <= reduced["window_s"] - reduced["busy_s"]
 
 
+def test_idle_gaps_are_named_by_the_innermost_engine_span(data, reduced):
+    """The gaps, the window and the busy time are what the harness's
+    spans alone gave; each gap now names the innermost ``bench.`` or
+    ``engine.`` span holding it: the largest lies in the logits copy."""
+    assert reduced["window_s"] == 0.139412758
+    assert reduced["busy_s"] == 0.124829634
+    assert reduced["idle_gaps"] == [
+        ["engine.logits_to_host", 0.005482491],
+        ["engine.operands", 0.003541856], ["engine.operands", 0.003356044],
+        ["engine.sample", 0.001287322], ["engine.sample", 0.000846698],
+        ["engine.sample", 5.6455e-05], ["engine.prefill.sample", 2.771e-06],
+        ["engine.prefill.sample", 1.897e-06],
+        ["engine.prefill.sample", 1.8e-06],
+        ["engine.prefill.sample", 1.638e-06]]
+    copy = [e for e in _events(data, "/host:CPU")
+            if e.name == "engine.logits_to_host"]
+    assert len(copy) == 1
+    assert copy[0].duration_ns / 1e9 > reduced["idle_gaps"][0][1]
+
+
 def test_readings_of_the_new_metrics(reduced):
     r = spans.readings(reduced)
     step_ms = 1e3 * reduced["modules"]["_decode"]["seconds"] / \
